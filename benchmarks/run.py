@@ -12,12 +12,15 @@ import argparse
 import sys
 import time
 
+from repro.utils import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    enable_compile_cache()
     fast = not args.full
     only = set(args.only.split(",")) if args.only else None
 

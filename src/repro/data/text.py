@@ -15,22 +15,31 @@ import numpy as np
 VOCAB = 256  # bytes
 
 
+# the tracked source trees; untracked output (logs, caches, traces) under
+# the checkout must not change what the corpus holds
+CORPUS_DIRS = ("src", "benchmarks", "examples", "tests")
+SPLIT_SEEDS = {"train": 0, "val": 1}
+
+
 def repo_corpus(root: str = None, max_bytes: int = 4 << 20) -> bytes:
-    """Concatenate this repo's text files into a corpus."""
+    """Concatenate this repo's source and docs into a corpus: the top-level
+    ``*.md`` files, then every ``.py``/``.md``/``.toml``/``.txt`` file
+    under ``CORPUS_DIRS``, each in sorted order."""
     root = root or os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(__file__))))
+    paths = [os.path.join(root, fn) for fn in sorted(os.listdir(root))
+             if fn.endswith(".md")]
+    for top in CORPUS_DIRS:
+        for dirpath, dirnames, files in os.walk(os.path.join(root, top)):
+            dirnames[:] = sorted(d for d in dirnames if not d.startswith(".")
+                                 and d not in ("results", "__pycache__"))
+            paths += [os.path.join(dirpath, fn) for fn in sorted(files)
+                      if fn.endswith((".py", ".md", ".toml", ".txt"))]
     buf = bytearray()
-    for dirpath, _, files in sorted(os.walk(root)):
-        if any(part.startswith(".") or part in ("results", "__pycache__") for part in dirpath.split(os.sep)):
-            continue
-        for fn in sorted(files):
-            if fn.endswith((".py", ".md", ".toml", ".txt")):
-                try:
-                    with open(os.path.join(dirpath, fn), "rb") as f:
-                        buf += f.read()
-                except OSError:
-                    continue
-            if len(buf) >= max_bytes:
-                return bytes(buf[:max_bytes])
+    for path in paths:
+        with open(path, "rb") as f:
+            buf += f.read()
+        if len(buf) >= max_bytes:
+            return bytes(buf[:max_bytes])
     return bytes(buf)
 
 
@@ -47,7 +56,8 @@ class ByteCorpus:
     def batch(self, step: int, batch: int, seq_len: int, split: str = "train"):
         n = len(self.arr) - seq_len - 1
         train_cut = int(n * 0.9)
-        rng = np.random.default_rng(np.random.SeedSequence([self.seed, step, hash(split) % (2**31)]))
+        rng = np.random.default_rng(
+            np.random.SeedSequence([self.seed, step, SPLIT_SEEDS[split]]))
         if split == "train":
             starts = rng.integers(0, train_cut, batch)
         else:

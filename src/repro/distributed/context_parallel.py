@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import scan as scan_lib
-from repro.utils import shard_map as shard_map_compat
 
 
 def stlt_context_parallel(
@@ -80,7 +79,7 @@ def stlt_context_parallel(
         )
         return z_loc + corr.astype(z_loc.dtype)
 
-    shmap = shard_map_compat(
+    shmap = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(None, axis, None), P(None), P(None), P(None), P(None)),

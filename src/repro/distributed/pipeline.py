@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.utils import shard_map as shard_map_compat
 
 
 def pipeline_apply(
@@ -69,7 +68,7 @@ def pipeline_apply(
         outs = jax.lax.ppermute(outs, axis, [((D - 1 + i) % D, i) for i in range(D)])
         return outs
 
-    shmap = shard_map_compat(
+    shmap = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis), P(None)),

@@ -76,12 +76,12 @@ def _toeplitz(g):
     return jnp.where(diff >= 0, g[:, jnp.clip(diff, 0, C - 1)], 0.0)
 
 
-def _snapshot_ops(log_mag, theta, valid, n_tokens: int, chunk: int, nc: int):
+def _snapshot_ops(log_mag, theta, valid, n_tokens: int, chunk: int):
     """Per-row carry-snapshot operators for a snapshot at token ``valid[row]``
     (or ``n_tokens`` when valid is None), kernel-shaped.
 
-    Returns (spre, spim [BH, S, C], sdec [BH, 2, S], gate [BH, nc] int32):
-    the gated chunk c* = (q-1)//C evaluates h_q = S @ X_c* + lambda^r h_c*
+    Returns (spre, spim [BH, S, C], sdec [BH, 2, S], snap [BH] int32):
+    the snapshot chunk c* = (q-1)//C evaluates h_q = S @ X_c* + lambda^r h_c*
     with r the in-chunk offset — the closed-form per-row carry correction
     that makes padded tails and non-multiple lengths exact in ONE pass. The
     operator math lives in ``scan_lib.stlt_snapshot_operators`` (shared
@@ -99,8 +99,8 @@ def _snapshot_ops(log_mag, theta, valid, n_tokens: int, chunk: int, nc: int):
     spim = jnp.transpose(w_im, (0, 2, 1))
     sdec = jnp.stack([d_re, d_im], axis=1)                   # [BH, 2, S]
     # valid == 0 rows never fire (their snapshot is h0, written at c == 0)
-    gate = (jnp.arange(nc)[None, :] == cstar[:, None]) & (q > 0)[:, None]
-    return spre, spim, sdec, gate.astype(jnp.int32)
+    snap = jnp.where(q > 0, cstar, -1).astype(jnp.int32)
+    return spre, spim, sdec, snap
 
 
 def _run_kernel(x, log_mag, theta, u_re, u_im, chunk, reverse, interpret,
@@ -126,8 +126,7 @@ def _run_kernel(x, log_mag, theta, u_re, u_im, chunk, reverse, interpret,
     g, A, B, pre, pim, dec = _filter_ops(
         lm, th, u_re.astype(jnp.float32), u_im.astype(jnp.float32), chunk)
     m = _toeplitz(g)
-    nc = xf.shape[1] // chunk
-    spre, spim, sdec, gate = _snapshot_ops(lm, th, valid, N, chunk, nc)
+    spre, spim, sdec, snap = _snapshot_ops(lm, th, valid, N, chunk)
     if h0_re is None:
         h0r = jnp.zeros((BH, S, dp), jnp.float32)
         h0i = h0r
@@ -138,7 +137,7 @@ def _run_kernel(x, log_mag, theta, u_re, u_im, chunk, reverse, interpret,
             h0r = jnp.pad(h0r, ((0, 0), (0, 0), (0, pad_d)))
             h0i = jnp.pad(h0i, ((0, 0), (0, 0), (0, pad_d)))
     z, h_re, h_im = stlt_scan_kernel(
-        gate, xf, m, A, B, pre, pim, dec, h0r, h0i, spre, spim, sdec,
+        snap, xf, m, A, B, pre, pim, dec, h0r, h0i, spre, spim, sdec,
         chunk=chunk, block_d=block_d, interpret=interpret)
     if pad_n or pad_d:
         z = z[:, :N, :d]

@@ -48,9 +48,9 @@ more than one [T, T] score tile (DESIGN.md §3):
   [N, S, dh] materialization, mirroring the PR-5 recompute philosophy.
 
 VMEM budget per grid cell is O(T^2 * S) for the Toeplitz operators plus
-O(T * S * dh) for the coefficient tiles — independent of N. At the
-default T=128 the operators dominate (2 * T*S * T floats, x2 again when
-bidirectional); shrink ``tile`` if S*dh is large.
+O(T * S * dh) for the coefficient tiles — independent of N, and above the
+TPU compiler's default scoped limit at the paper's widths, so the kernel
+asks for what ``_vmem_limit_bytes`` counts.
 """
 from __future__ import annotations
 
@@ -61,19 +61,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces (used for scratch); interpret mode accepts them too
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-    try:
-        _CompilerParams = pltpu.CompilerParams
-    except AttributeError:  # older naming
-        _CompilerParams = pltpu.TPUCompilerParams
-except Exception:  # pragma: no cover - non-TPU builds
-    pltpu = None
-    _VMEM = None
-    _CompilerParams = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30  # finite -inf stand-in: exp underflows to exact 0, no NaNs
 
@@ -251,7 +239,7 @@ def _flash_body(*refs, tile: int, S: int, dh: int, causal: bool):
             gq_re, gq_im = gq_re_ref[0, 0], gq_im_ref[0, 0]
         ql_re, ql_im = rec(xq_ref[0], hq_re_ref[0, 0], hq_im_ref[0, 0],
                            gq_re, gq_im)
-        mk = mk_ref[0]  # adaptive node masks fold query-side (Lw . conj L)
+        mk = mk_ref[0, 0]  # adaptive node masks fold query-side (Lw . conj L)
         qre_s[...] = (ql_re * mk[None, :, None]).reshape(T, S * dh)
         qim_s[...] = (ql_im * mk[None, :, None]).reshape(T, S * dh)
         m_s[...] = jnp.full((T, 1), _NEG, jnp.float32)
@@ -274,7 +262,7 @@ def _flash_body(*refs, tile: int, S: int, dh: int, causal: bool):
                                  preferred_element_type=jnp.float32)
         r *= 1.0 / math.sqrt(S)
 
-        valid = km_ref[0][None, :] > 0.0                       # [T, T]
+        valid = km_ref[0] > 0.0                                # [1, T]
         if causal:
             rows = qi * T + jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
             cols = ki * T + jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
@@ -301,6 +289,40 @@ def _flash_body(*refs, tile: int, S: int, dh: int, causal: bool):
         z_ref[0] = jnp.where(l > 0, acc_s[...] / safe, 0.0)
 
 
+def _vmem_limit_bytes(T: int, S: int, dh: int, causal: bool) -> int:
+    """Scoped-VMEM budget of one grid cell, from the block shapes.
+
+    Every buffer is float32 and lies in (8, 128) tiles, so a dh = 64 row
+    takes 128 lanes. The pipeline double-buffers each block: x_q, x_k, v
+    and z (4 * T x dh), four tile carries (4 * S x dh), the two flattened
+    Toeplitz operators (2 * T*S x T), the two injections (2 * T x S) and
+    the two masks (1 x S, 1 x T). Bidirectional tiles double the carries,
+    operators and injections. Scratch is single: the query coefficients
+    (2 * T x S*dh), m and l (2 * T x 1) and the accumulator (T x dh).
+    The body's [T*S, dh] coefficient temporaries come on top: the TPU
+    compiler keeps 7 of them live causal and 16 bidirectional (read off its
+    report at T = 128, S = 64, dh = 64; a "highest"-precision matmul splits
+    its f32 operands into bf16 parts, which takes 9.6 MiB more causal).
+    4 MiB more covers Mosaic's internal scratch (2 MiB there).
+
+    At T = 128, S = 64, dh = 64 that is 53.2 MiB causal (the compiler asked
+    for 39.8 MiB at the default precision and 49.4 MiB at "highest",
+    against a default scoped limit of 16 MiB) and 105.7 MiB bidirectional
+    (it asked for 101.8 and 100.0 MiB); the v5e has 128 MiB of VMEM. Never
+    below the default limit.
+    """
+    def tile(rows, cols):
+        return -(-rows // 8) * 8 * (-(-cols // 128)) * 128
+
+    per_dir = 4 * tile(S, dh) + 2 * tile(T * S, T) + 2 * tile(T, S)
+    blocks = 4 * tile(T, dh) + per_dir + tile(1, S) + tile(1, T)
+    if not causal:
+        blocks += per_dir
+    scratch = 2 * tile(T, S * dh) + 2 * tile(T, 1) + tile(T, dh)
+    temps = (7 if causal else 16) * tile(T * S, dh)
+    return max(16 << 20, 4 * (2 * blocks + scratch + temps) + (4 << 20))
+
+
 @functools.partial(jax.jit, static_argnames=("tile", "causal", "interpret"))
 def relevance_flash_kernel(x, v, mk, km, ops, *, tile: int,
                            causal: bool, interpret: bool = False):
@@ -316,29 +338,32 @@ def relevance_flash_kernel(x, v, mk, km, ops, *, tile: int,
     nt = Np // T
     grid = (BH, nt, nt)
 
-    def bix(f):
-        return lambda bh, qi, ki: f(bh, qi, ki)
+    def q_idx(bh, qi, ki):
+        return (bh, qi, 0)
 
-    q_idx = bix(lambda bh, qi, ki: (bh, qi, 0))
-    k_idx = bix(lambda bh, qi, ki: (bh, ki, 0))
-    op_idx = bix(lambda bh, qi, ki: (bh, 0, 0))
-    qc_idx = bix(lambda bh, qi, ki: (bh, qi, 0, 0))
-    kc_idx = bix(lambda bh, qi, ki: (bh, ki, 0, 0))
+    def k_idx(bh, qi, ki):
+        return (bh, ki, 0)
+
+    def op_idx(bh, qi, ki):
+        return (bh, 0, 0)
 
     xspec_q = pl.BlockSpec((1, T, dh), q_idx)
     xspec_k = pl.BlockSpec((1, T, dh), k_idx)
-    cspec_q = pl.BlockSpec((1, 1, S, dh), qc_idx)
-    cspec_k = pl.BlockSpec((1, 1, S, dh), kc_idx)
+    cspec_q = pl.BlockSpec((1, 1, S, dh), lambda bh, qi, ki: (bh, qi, 0, 0))
+    cspec_k = pl.BlockSpec((1, 1, S, dh), lambda bh, qi, ki: (bh, ki, 0, 0))
     tri_spec = pl.BlockSpec((1, T * S, T), op_idx)
     inj_spec = pl.BlockSpec((1, T, S), op_idx)
 
+    # masks ride as [BH, 1, S] / [BH, 1, Np]: a block's last two dims must
+    # match the array's or be (8, 128)-aligned, and a (1, S) block of a
+    # [BH, S] array is neither
     inputs = [x, x, v, ops["hc_re"], ops["hc_im"], ops["hc_re"], ops["hc_im"],
               ops["tri2t_re"], ops["tri2t_im"], ops["inj_re"], ops["inj_im"],
-              mk, km]
+              mk[:, None, :], km[:, None, :]]
     in_specs = [xspec_q, xspec_k, xspec_k, cspec_q, cspec_q, cspec_k, cspec_k,
                 tri_spec, tri_spec, inj_spec, inj_spec,
-                pl.BlockSpec((1, S), bix(lambda bh, qi, ki: (bh, 0))),
-                pl.BlockSpec((1, T), bix(lambda bh, qi, ki: (bh, ki)))]
+                pl.BlockSpec((1, 1, S), op_idx),
+                pl.BlockSpec((1, 1, T), lambda bh, qi, ki: (bh, 0, ki))]
     if not causal:
         inputs += [ops["gc_re"], ops["gc_im"], ops["gc_re"], ops["gc_im"],
                    ops["rtri2t_re"], ops["rtri2t_im"],
@@ -346,18 +371,11 @@ def relevance_flash_kernel(x, v, mk, km, ops, *, tile: int,
         in_specs += [cspec_q, cspec_q, cspec_k, cspec_k,
                      tri_spec, tri_spec, inj_spec, inj_spec]
 
-    scratch = [
-        _VMEM((T, S * dh), jnp.float32) if _VMEM else pl.BlockSpec(memory_space=None),
-        _VMEM((T, S * dh), jnp.float32) if _VMEM else pl.BlockSpec(memory_space=None),
-        _VMEM((T, 1), jnp.float32) if _VMEM else pl.BlockSpec(memory_space=None),
-        _VMEM((T, 1), jnp.float32) if _VMEM else pl.BlockSpec(memory_space=None),
-        _VMEM((T, dh), jnp.float32) if _VMEM else pl.BlockSpec(memory_space=None),
-    ]
-    kwargs = {}
-    if _CompilerParams is not None and not interpret:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")
-        )
+    scratch = [pltpu.VMEM((T, S * dh), jnp.float32),
+               pltpu.VMEM((T, S * dh), jnp.float32),
+               pltpu.VMEM((T, 1), jnp.float32),
+               pltpu.VMEM((T, 1), jnp.float32),
+               pltpu.VMEM((T, dh), jnp.float32)]
     body = functools.partial(_flash_body, tile=T, S=S, dh=dh, causal=causal)
     return pl.pallas_call(
         body,
@@ -366,8 +384,10 @@ def relevance_flash_kernel(x, v, mk, km, ops, *, tile: int,
         out_specs=[pl.BlockSpec((1, T, dh), q_idx)],
         out_shape=[jax.ShapeDtypeStruct((BH, Np, dh), jnp.float32)],
         scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit_bytes(T, S, dh, causal)),
         interpret=interpret,
-        **kwargs,
     )(*inputs)[0]
 
 

@@ -26,12 +26,15 @@ The emitted carry is a per-row SNAPSHOT at token index ``valid[row]``
 
     Spre/Spim[k,j] = Re/Im(lambda_k^(r-1-j)) for j < r, else 0
     sdec           = lambda^r,   r = in-chunk offset of valid[row]
-    gate[row, c]   = 1 iff chunk c contains valid[row]
+    snap[row]      = the chunk that contains valid[row] (-1: never)
 
 and the kernel evaluates ``h_valid = S @ X_c + sdec * h_chunk_start`` in the
-ONE gated chunk — this is how padded tail chunks (two-shape serving) leave
-the carry exactly where the unpadded chunk would, without a second pass.
-Rows with ``valid == 0`` return ``h0`` (written at c == 0, gate never fires).
+ONE chunk with ``c == snap[row]`` — this is how padded tail chunks
+(two-shape serving) leave the carry exactly where the unpadded chunk would,
+without a second pass.
+Rows with ``valid == 0`` return ``h0`` (written at c == 0, snap == -1).
+``snap`` is a scalar-prefetch operand: it sits in SMEM before the grid runs,
+so it costs one int32 per row whatever N is.
 
 Grid: (BH, d/bd, N/C) with the chunk axis sequential ("arbitrary"), a VMEM
 scratch pair for the running carry, and the carry outputs as revisited
@@ -47,29 +50,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces (used for scratch); interpret mode accepts them too
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-    _SMEM = pltpu.SMEM
-    try:
-        _CompilerParams = pltpu.CompilerParams
-    except AttributeError:  # older naming
-        _CompilerParams = pltpu.TPUCompilerParams
-except Exception:  # pragma: no cover - non-TPU builds
-    pltpu = None
-    _VMEM = None
-    _SMEM = None
-    _CompilerParams = None
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(gate_ref, x_ref, m_ref, a_ref, b_ref, pre_ref, pim_ref, dec_ref,
+def _kernel(snap_ref, x_ref, m_ref, a_ref, b_ref, pre_ref, pim_ref, dec_ref,
             h0re_ref, h0im_ref, spre_ref, spim_ref, sdec_ref,
             z_ref, hre_ref, him_ref, cre_ref, cim_ref):
     """One (row, d-block, chunk) grid step. cre/cim: running-carry scratch;
     hre/him: the snapshot carry output (a revisited block, written in the
-    gated chunk — or h0 at c == 0 for valid == 0 rows)."""
+    chunk ``snap[row]`` — or h0 at c == 0 for valid == 0 rows)."""
     c = pl.program_id(2)
 
     @pl.when(c == 0)
@@ -97,7 +86,7 @@ def _kernel(gate_ref, x_ref, m_ref, a_ref, b_ref, pre_ref, pim_ref, dec_ref,
     z_ref[0] = z.astype(z_ref.dtype)
 
     # Carry snapshot at this row's valid position (one chunk per row fires).
-    @pl.when(gate_ref[0, 0] > 0)
+    @pl.when(snap_ref[pl.program_id(0)] == c)
     def _snapshot():
         spre = spre_ref[0]         # [S, C]  lambda^(r-1-j), zero for j >= r
         spim = spim_ref[0]
@@ -119,14 +108,14 @@ def _kernel(gate_ref, x_ref, m_ref, a_ref, b_ref, pre_ref, pim_ref, dec_ref,
 @functools.partial(
     jax.jit, static_argnames=("chunk", "block_d", "interpret")
 )
-def stlt_scan_kernel(gate, x, m, a, b, pre, pim, dec, h0_re, h0_im,
+def stlt_scan_kernel(snap, x, m, a, b, pre, pim, dec, h0_re, h0_im,
                      spre, spim, sdec, *, chunk: int = 128,
                      block_d: int = 128, interpret: bool = False):
     """x [BH, N, d] (N % chunk == 0, d % block_d == 0); operators per row.
 
     m [BH, C, C]; a,b [BH, C, S]; pre,pim,spre,spim [BH, S, C];
-    dec,sdec [BH, 2, S]; h0_re/h0_im [BH, S, d]; gate [BH, nc] int32
-    (exactly one 1 per row with valid > 0, all 0 for valid == 0).
+    dec,sdec [BH, 2, S]; h0_re/h0_im [BH, S, d]; snap [BH] int32, the
+    chunk holding each row's snapshot (-1 for valid == 0: never taken).
     Returns (z [BH, N, d], h_re [BH, S, d], h_im [BH, S, d]) float32 — the
     carry outputs are the per-row snapshot states (see module docstring).
     """
@@ -134,51 +123,46 @@ def stlt_scan_kernel(gate, x, m, a, b, pre, pim, dec, h0_re, h0_im,
     S = pre.shape[1]
     assert N % chunk == 0 and d % block_d == 0, (N, chunk, d, block_d)
     nc, nd = N // chunk, d // block_d
-    assert gate.shape == (BH, nc), (gate.shape, BH, nc)
+    assert snap.shape == (BH,), (snap.shape, BH)
 
-    grid = (BH, nd, nc)
-    kwargs = {}
-    if _CompilerParams is not None and not interpret:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    scratch = [
-        _VMEM((S, block_d), jnp.float32) if _VMEM else
-        pl.BlockSpec(memory_space=None),
-        _VMEM((S, block_d), jnp.float32) if _VMEM else
-        pl.BlockSpec(memory_space=None),
-    ]
-    gate_spec_kwargs = {"memory_space": _SMEM} if _SMEM is not None else {}
-    z, h_re, h_im = pl.pallas_call(
-        _kernel,
-        grid=grid,
+    def op(shape):  # per-row operator block, fixed across d-blocks/chunks
+        return pl.BlockSpec((1,) + shape, lambda bh, db, c, s: (bh, 0, 0))
+
+    def cols(shape):  # per-row block that walks the d-blocks
+        return pl.BlockSpec((1,) + shape, lambda bh, db, c, s: (bh, 0, db))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(BH, nd, nc),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda bh, db, c: (bh, c), **gate_spec_kwargs),
-            pl.BlockSpec((1, chunk, block_d), lambda bh, db, c: (bh, c, db)),
-            pl.BlockSpec((1, chunk, chunk), lambda bh, db, c: (bh, 0, 0)),
-            pl.BlockSpec((1, chunk, S), lambda bh, db, c: (bh, 0, 0)),
-            pl.BlockSpec((1, chunk, S), lambda bh, db, c: (bh, 0, 0)),
-            pl.BlockSpec((1, S, chunk), lambda bh, db, c: (bh, 0, 0)),
-            pl.BlockSpec((1, S, chunk), lambda bh, db, c: (bh, 0, 0)),
-            pl.BlockSpec((1, 2, S), lambda bh, db, c: (bh, 0, 0)),
-            pl.BlockSpec((1, S, block_d), lambda bh, db, c: (bh, 0, db)),
-            pl.BlockSpec((1, S, block_d), lambda bh, db, c: (bh, 0, db)),
-            pl.BlockSpec((1, S, chunk), lambda bh, db, c: (bh, 0, 0)),
-            pl.BlockSpec((1, S, chunk), lambda bh, db, c: (bh, 0, 0)),
-            pl.BlockSpec((1, 2, S), lambda bh, db, c: (bh, 0, 0)),
+            pl.BlockSpec((1, chunk, block_d),
+                         lambda bh, db, c, s: (bh, c, db)),
+            op((chunk, chunk)),
+            op((chunk, S)), op((chunk, S)),
+            op((S, chunk)), op((S, chunk)),
+            op((2, S)),
+            cols((S, block_d)), cols((S, block_d)),
+            op((S, chunk)), op((S, chunk)),
+            op((2, S)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, block_d), lambda bh, db, c: (bh, c, db)),
-            pl.BlockSpec((1, S, block_d), lambda bh, db, c: (bh, 0, db)),
-            pl.BlockSpec((1, S, block_d), lambda bh, db, c: (bh, 0, db)),
+            pl.BlockSpec((1, chunk, block_d),
+                         lambda bh, db, c, s: (bh, c, db)),
+            cols((S, block_d)), cols((S, block_d)),
         ],
+        scratch_shapes=[pltpu.VMEM((S, block_d), jnp.float32),
+                        pltpu.VMEM((S, block_d), jnp.float32)],
+    )
+    z, h_re, h_im = pl.pallas_call(
+        _kernel,
+        grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((BH, N, d), jnp.float32),
             jax.ShapeDtypeStruct((BH, S, d), jnp.float32),
             jax.ShapeDtypeStruct((BH, S, d), jnp.float32),
         ],
-        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
-    )(gate, x, m, a, b, pre, pim, dec, h0_re, h0_im, spre, spim, sdec)
+    )(snap, x, m, a, b, pre, pim, dec, h0_re, h0_im, spre, spim, sdec)
     return z, h_re, h_im

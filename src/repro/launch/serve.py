@@ -63,7 +63,7 @@ from repro.serving import (
     ShardedServeEngine,
 )
 from repro.serving.engine import Request
-from repro.utils import cast_params_for_compute, tree_size
+from repro.utils import cast_params_for_compute, enable_compile_cache, tree_size
 
 
 def main(argv=None):
@@ -162,6 +162,7 @@ def main(argv=None):
                     help="remote prefill workers to wait for "
                          "(--role controller)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.role == "prefill":
         # a prefill worker builds everything from the controller's config

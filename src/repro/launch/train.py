@@ -29,7 +29,7 @@ from repro.launch import steps as steps_lib
 from repro.models import transformer as T
 from repro.optim import clip_by_global_norm, make_optimizer, make_schedule
 from repro.optim.adamw import apply_updates
-from repro.utils import cast_params_for_compute, tree_size
+from repro.utils import cast_params_for_compute, enable_compile_cache, tree_size
 
 
 def paper_small(vocab: int = 256) -> ModelConfig:
@@ -67,6 +67,8 @@ def make_step(cfg: ModelConfig, tcfg: TrainConfig):
 
 
 def main(argv=None):
+    """Train; returns the final ``{"params", "opt"}`` state and the logged
+    metrics, one dict per logged step."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, help="assigned arch id")
     ap.add_argument("--preset", default=None, choices=[None, "paper-small"])
@@ -81,6 +83,7 @@ def main(argv=None):
     ap.add_argument("--save-every", type=int, default=100)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.preset == "paper-small" or args.arch is None:
         cfg = paper_small()
@@ -117,6 +120,7 @@ def main(argv=None):
           f"{jax.device_count()} device(s), resume from step {start}")
 
     t_last, tok_per_step = time.time(), args.batch * args.seq
+    log = []
     for step in range(start + 1, args.steps):
         batch = {k: jnp.asarray(v) for k, v in batch_fn(step).items()
                  if k in ("inputs", "labels", "mask")}
@@ -126,6 +130,7 @@ def main(argv=None):
             dt = time.time() - t_last
             t_last = time.time()
             m = {k: float(v) for k, v in metrics.items()}
+            log.append({"step": step, **m})
             print(f"[train] step {step:5d} loss {m['loss']:.4f} ce {m['ce']:.4f} "
                   f"gnorm {m['grad_norm']:.2f} s_eff {m.get('s_eff', 0):.1f} "
                   f"({tok_per_step * args.log_every / max(dt, 1e-9):.0f} tok/s)")
@@ -135,7 +140,7 @@ def main(argv=None):
         mgr.save(args.steps - 1, state)
         mgr.wait()
     print("[train] done")
-    return state
+    return state, log
 
 
 if __name__ == "__main__":

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 
 from repro.models import layers as L
 from repro.utils import lecun_normal
-from repro.utils import shard_map as shard_map_compat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -259,7 +258,7 @@ def _apply_moe_shardmap(params, cfg: MoEConfig, x: jax.Array, mesh):
     w_in = {k: params[k] for k in ("router", "w1", "w3", "w2")}
     if cfg.dense_residual:
         w_in["dense"] = params["dense"]
-    y, aux = shard_map_compat(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )(w_in, x)

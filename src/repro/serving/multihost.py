@@ -46,24 +46,31 @@ parity vs the single-host engine, under forced host devices).
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import decode_state_specs
 from repro.models import transformer as T
 from repro.serving.engine import Scheduler, ServeEngine, _Host
 from repro.serving.prefix_cache import PrefixCache, ReplicatedPrefixCache
-from repro.utils import shard_map
+from repro.serving.sampler import sample_slot_tokens
 
 
 def make_serve_mesh(n_hosts: int):
     """The serving mesh: 1-D ``("data",)`` over ``n_hosts`` devices (the
-    slot pool's batch axis lives on ``data``; params are replicated)."""
+    slot pool's batch axis lives on ``data``; params are replicated).
+
+    The axis is ``Auto``: the shard_map'd dispatches state their own
+    layouts, and the host-side glue between them (row slices for promote
+    and splice, sampling) takes the pool's sharding as a layout, not as
+    part of the array type as an ``Explicit`` axis (``jax.make_mesh``'s
+    default) would."""
     if n_hosts < 1:
         raise ValueError(f"n_hosts must be >= 1 (got {n_hosts})")
     if n_hosts > jax.device_count():
@@ -71,7 +78,7 @@ def make_serve_mesh(n_hosts: int):
             f"n_hosts={n_hosts} exceeds {jax.device_count()} available "
             "devices (force host devices via XLA_FLAGS="
             "--xla_force_host_platform_device_count=N)")
-    return jax.make_mesh((n_hosts,), ("data",))
+    return jax.make_mesh((n_hosts,), ("data",), axis_types=(AxisType.Auto,))
 
 
 class ShardedServeEngine(ServeEngine):
@@ -182,21 +189,28 @@ class ShardedServeEngine(ServeEngine):
                 lambda x: jax.lax.psum(
                     jnp.where(owns, x, jnp.zeros_like(x)), "data"), row)
 
-        self._step_sh = jax.jit(shard_map(
-            _step_body, mesh_, in_specs=(rep, P("data"), spec, P("data")),
+        self._step_sh = jax.jit(jax.shard_map(
+            _step_body, mesh=mesh_, in_specs=(rep, P("data"), spec, P("data")),
             out_specs=(P("data"), spec)))
-        self._prefill_sh = jax.jit(shard_map(
-            _prefill_body, mesh_,
+        self._prefill_sh = jax.jit(jax.shard_map(
+            _prefill_body, mesh=mesh_,
             in_specs=(rep, P("data"), spec, P("data")),
             out_specs=(P("data"), spec)))
-        self._verify_sh = jax.jit(shard_map(
-            _verify_body, mesh_,
+        self._verify_sh = jax.jit(jax.shard_map(
+            _verify_body, mesh=mesh_,
             in_specs=(rep, P("data"), spec, P("data"), P("data")),
             out_specs=(P("data"), P("data"), spec)))
-        self._insert_sh = jax.jit(shard_map(
-            _insert_body, mesh_, in_specs=(spec, rep, rep), out_specs=spec))
-        self._extract_sh = jax.jit(shard_map(
-            _extract_body, mesh_, in_specs=(spec, rep), out_specs=rep))
+        self._insert_sh = jax.jit(jax.shard_map(
+            _insert_body, mesh=mesh_, in_specs=(spec, rep, rep), out_specs=spec))
+        self._extract_sh = jax.jit(jax.shard_map(
+            _extract_body, mesh=mesh_, in_specs=(spec, rep), out_specs=rep))
+        # each shard samples its own rows: the decode logits come out laid
+        # over "data", and the per-slot keys and temperatures are sliced
+        # the same way on entry, so no shard gathers another's logits
+        self._sample = jax.jit(jax.shard_map(
+            partial(sample_slot_tokens, top_k=top_k), mesh=mesh_,
+            in_specs=(P("data"), P("data"), P("data")),
+            out_specs=P("data")))
         # pristine batch-1 template: seeds fresh prefills and resets rows
         self._fresh1 = T.init_decode_state(cfg, 1, max_len)
 

@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import os
 from typing import Any, Callable, Iterable, Mapping
 
 import jax
@@ -186,21 +187,21 @@ def trace_probe(fn: Callable, log: list, name: str | None = None) -> Callable:
     return probed
 
 
-def shard_map(f, mesh, in_specs, out_specs, **kwargs):
-    """Version-compatible ``shard_map``.
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
-    Newer jax exposes ``jax.shard_map`` (with a ``check_vma`` kwarg); older
-    releases only have ``jax.experimental.shard_map.shard_map`` (with
-    ``check_rep``). Dispatch to whichever exists and translate the
-    replication-check kwarg to the installed spelling.
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set here. Otherwise the cache goes to ``.jax_cache`` at the
+    root of the checkout: a fixed path, because the path is part of what a
+    later run must find again. Entry points call this from ``main()``,
+    never at import.
     """
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    import inspect
-
-    accepted = inspect.signature(fn).parameters
-    for ours, theirs in (("check_vma", "check_rep"), ("check_rep", "check_vma")):
-        if ours in kwargs and ours not in accepted and theirs in accepted:
-            kwargs[theirs] = kwargs.pop(ours)
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(_REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -1,4 +1,8 @@
 """Data pipeline: determinism, structure, pipeline prefetch, corpus."""
+import os
+import subprocess
+import sys
+
 import numpy as np
 
 from repro.data import ByteCorpus, DataPipeline, copy_task_batch, lm_batch_stream, needle_batch
@@ -40,6 +44,36 @@ def test_byte_corpus_split_and_determinism():
     np.testing.assert_array_equal(b1["inputs"][:, 1:], b1["labels"][:, :-1])
     v = c.batch(3, 2, 16, split="val")
     assert not np.array_equal(v["inputs"], b1["inputs"])
+
+
+def test_byte_corpus_batches_match_across_processes():
+    """Batches depend on (seed, step, split) only: Python salts ``hash`` per
+    process, so a split seed taken from it would differ from run to run."""
+    code = ("import sys; from repro.data import ByteCorpus; "
+            "c = ByteCorpus(bytes(range(256)) * 300); "
+            "sys.stdout.write(c.batch(3, 2, 16, split='val')['inputs'].tobytes().hex())")
+    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
+    outs = []
+    for salt in ("1", "2"):
+        env = {**os.environ, "PYTHONHASHSEED": salt, "PYTHONPATH": src}
+        outs.append(subprocess.run([sys.executable, "-c", code], env=env,
+                                   capture_output=True, text=True,
+                                   check=True, timeout=120).stdout)
+    assert outs[0] and outs[0] == outs[1]
+
+
+def test_repo_corpus_reads_tracked_trees_only(tmp_path):
+    from repro.data.text import repo_corpus
+
+    (tmp_path / "src" / "pkg").mkdir(parents=True)
+    (tmp_path / "src" / "pkg" / "a.py").write_bytes(b"SOURCE\n")
+    (tmp_path / "README.md").write_bytes(b"DOCS\n")
+    (tmp_path / "smoke.log.txt").write_bytes(b"STRAY-TOP\n")
+    (tmp_path / "logs").mkdir()
+    (tmp_path / "logs" / "out.txt").write_bytes(b"STRAY-OUT\n")
+    (tmp_path / "src" / ".jax_cache").mkdir()
+    (tmp_path / "src" / ".jax_cache" / "x.txt").write_bytes(b"STRAY-CACHE\n")
+    assert repo_corpus(str(tmp_path)) == b"DOCS\nSOURCE\n"
 
 
 def test_pipeline_prefetch_order_and_resume():

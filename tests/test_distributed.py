@@ -131,7 +131,6 @@ def test_gradient_compression_halves_wire_bytes():
     _run_subprocess(r"""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.utils import shard_map
         mesh = jax.make_mesh((4,), ("data",))
 
         def allreduce(x, compress):
@@ -139,8 +138,8 @@ def test_gradient_compression_halves_wire_bytes():
                 g = x.astype(jnp.bfloat16) if compress else x
                 s = jax.lax.psum(g, "data")
                 return s.astype(jnp.float32)
-            return jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"),
-                                     out_specs=P(None), check_vma=False))(x)
+            return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                                         out_specs=P(None), check_vma=False))(x)
 
         x = jnp.ones((4, 1024), jnp.float32)
         # NB: inspect the PRE-backend lowering — the CPU backend legalizes
