@@ -63,6 +63,7 @@ from repro.serving import (
     ShardedServeEngine,
 )
 from repro.serving.engine import Request
+from repro.serving.trace import summary as tick_summary
 from repro.utils import cast_params_for_compute, enable_compile_cache, tree_size
 
 
@@ -387,6 +388,8 @@ def main(argv=None):
             print(f"[serve] spec adapt: {ss['adapt_shrinks']} shrinks / "
                   f"{ss['adapt_restores']} restores "
                   f"(min k {ss['adapt_min_k']}, floor {ss['adapt_floor']})")
+    if eng.tick_stats["ticks"]:
+        print(f"[serve] tick phases: {tick_summary(eng.tick_stats)}")
     if ladder:
         ns = eng.node_stats
         print(f"[serve] slo ladder={ns['ladder']}: "

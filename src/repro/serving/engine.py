@@ -64,6 +64,11 @@ baseline for benchmarks/serving.py. Time is measured in ticks: one batched
 decode step == one tick, which is also the unit of the optional per-request
 ``arrivals`` trace and of the latency stats returned by
 ``serve(..., return_stats=True)``.
+
+Each phase of a tick (admission, prefill dispatch, promote, decode, the
+host's waits on the device, release) runs inside a profiler span named
+``serve.*`` and is counted in ``ServeEngine.tick_stats``, reset per serve
+run like ``spec_stats`` (``serving/trace.py``).
 """
 from __future__ import annotations
 
@@ -85,6 +90,16 @@ from repro.serving.sampler import (
     sample_token,
     split_slot_keys,
 )
+from repro.serving.trace import ADMIT, PROMOTE, RELEASE, TICK, TickTrace
+
+
+def _jit(fn, **bound):
+    """``jax.jit`` of ``fn`` with keyword arguments bound, named after
+    ``fn``: a ``functools.partial`` has no name of its own, so its program
+    would show as ``jit__unknown`` in a profiler trace, not ``jit_<fn>``."""
+    p = partial(fn, **bound)
+    p.__name__ = fn.__name__
+    return jax.jit(p)
 
 
 @dataclasses.dataclass
@@ -357,15 +372,19 @@ class ServeEngine:
         self.node_stats: dict = {}
         self._full_caps_cache: dict[int, jax.Array] = {}
         self.prefix_cache = prefix_cache
-        self._prefill = jax.jit(partial(T.prefill, cfg=cfg, max_len=max_len))
-        self._prefill_chunk = jax.jit(partial(T.prefill_chunk, cfg=cfg))
-        self._step = jax.jit(partial(T.decode_step, cfg=cfg))
-        self._verify = jax.jit(partial(T.spec_verify, cfg=cfg))
-        self._insert = jax.jit(partial(T.insert_slot, cfg=cfg))
-        self._extract = jax.jit(partial(T.extract_slot, cfg=cfg))
-        self._reset = jax.jit(partial(T.reset_slot, cfg=cfg, max_len=max_len))
-        self._sample = jax.jit(partial(sample_slot_tokens, top_k=top_k))
+        self._prefill = _jit(T.prefill, cfg=cfg, max_len=max_len)
+        self._prefill_chunk = _jit(T.prefill_chunk, cfg=cfg)
+        self._step = _jit(T.decode_step, cfg=cfg)
+        self._verify = _jit(T.spec_verify, cfg=cfg)
+        self._insert = _jit(T.insert_slot, cfg=cfg)
+        self._extract = _jit(T.extract_slot, cfg=cfg)
+        self._reset = _jit(T.reset_slot, cfg=cfg, max_len=max_len)
+        self._sample = _jit(sample_slot_tokens, top_k=top_k)
         self._split = jax.jit(split_slot_keys)
+        # spans and counters of the tick phases (serving/trace.py); the
+        # counters are reset at the top of every serve run, like spec_stats
+        self._trace = TickTrace()
+        self.tick_stats: dict = self._trace.stats
         self._fresh1 = None  # lazy pristine batch-1 template (_fresh_template)
         # only unbounded causal attention allocates a length-bounded cache;
         # windowed attention uses a ring and STLT/SSM states are O(1) in N
@@ -771,6 +790,7 @@ class ServeEngine:
         run.spec = self._make_draft(B)
         self.spec_stats = {"verify_calls": 0, "drafted": 0, "accepted": 0,
                            "emitted": 0, "k": self.spec_k}
+        self.tick_stats = self._trace.reset()
         if run.spec is not None and self.spec_adaptive:
             from repro.serving import speculative
             run.spec_adapt = speculative.AdaptiveK(
@@ -811,36 +831,41 @@ class ServeEngine:
     def _promote(self, run, h, local, ent, logits1, st1):
         """Prefill complete on host h: sample the first token, go live —
         unless a handoff hook claims the state for another fleet."""
-        sched = run.hosts[h].sched
-        req = ent["req"]
-        if self._handoff_promote(run, h, local, ent, logits1, st1):
-            # shipped elsewhere: free the slot without ever going live
-            sched.release(local, run.tick)
-            return
-        g = h * run.K + local
-        rkey = jax.random.fold_in(run.base_key, req.id)
-        # split BEFORE sampling/storing: k0 is consumed by the first
-        # token, the carried stream continues from the UNUSED half — no
-        # key is ever both consumed and carried (key reuse would
-        # correlate the first two draws of every sampled request)
-        carry, k0 = jax.random.split(rkey)
-        temp = self.temperature if req.temperature is None else req.temperature
-        t0 = int(sample_token(logits1, k0, temp, self.top_k)[0])
-        run.pool = self._ops_insert(self._ensure_pool(run), st1, g)
-        run.keys = run.keys.at[g].set(carry)
-        run.tok[g] = t0
-        run.temps[g] = temp
-        sched.activate(local, run.tick)
-        run.results[req.id] = [t0]
-        sched.stats[req.id]["token_walls"].append(self._now())
-        sched.emitted[local] = 1
-        if sched.emitted[local] >= sched.budgets[local] or t0 == self.eos_id:
-            sched.release(local, run.tick)   # prefill-only request
-            run.pool = self._ops_reset(run.pool, g)
-        elif run.spec is not None:
-            run.spec.on_promote(g, ent["prompt"], t0)
-            if run.spec_adapt is not None:
-                run.spec_adapt.reset(g)
+        with self._trace.phase(PROMOTE, req=ent["req"].id):
+            sched = run.hosts[h].sched
+            req = ent["req"]
+            if self._handoff_promote(run, h, local, ent, logits1, st1):
+                # shipped elsewhere: free the slot without ever going live
+                sched.release(local, run.tick)
+                return
+            g = h * run.K + local
+            rkey = jax.random.fold_in(run.base_key, req.id)
+            # split BEFORE sampling/storing: k0 is consumed by the first
+            # token, the carried stream continues from the UNUSED half — no
+            # key is ever both consumed and carried (key reuse would
+            # correlate the first two draws of every sampled request)
+            carry, k0 = jax.random.split(rkey)
+            temp = (self.temperature if req.temperature is None
+                    else req.temperature)
+            first = sample_token(logits1, k0, temp, self.top_k)
+            with self._trace.sync():
+                t0 = int(first[0])
+            run.pool = self._ops_insert(self._ensure_pool(run), st1, g)
+            run.keys = run.keys.at[g].set(carry)
+            run.tok[g] = t0
+            run.temps[g] = temp
+            sched.activate(local, run.tick)
+            run.results[req.id] = [t0]
+            sched.stats[req.id]["token_walls"].append(self._now())
+            sched.emitted[local] = 1
+            if (sched.emitted[local] >= sched.budgets[local]
+                    or t0 == self.eos_id):
+                sched.release(local, run.tick)   # prefill-only request
+                run.pool = self._ops_reset(run.pool, g)
+            elif run.spec is not None:
+                run.spec.on_promote(g, ent["prompt"], t0)
+                if run.spec_adapt is not None:
+                    run.spec_adapt.reset(g)
 
     def _tick_admission(self, run):
         """Admission phase of one tick: fill free local rows from host
@@ -851,78 +876,83 @@ class ServeEngine:
         hosts, K, B = run.hosts, run.K, run.B
         chunk_size, coalesce = run.chunk_size, run.coalesce
         # --- per-host admission into free local rows --------------------
-        for h, host in enumerate(hosts):
-            sched = host.sched
-            for local in sched.free_slots():
-                if not host.queue:
-                    break
-                arrival, req = host.queue.pop(0)
-                prompt = self._padded(req.prompt, run.prompt_len)
-                ready = self._ready_state(req)
-                if ready is not None:
-                    # prefilled elsewhere (disagg handoff): splice + promote
-                    # with zero local prefill work — the whole prompt counts
-                    # as cached on this host, exactly like a full-prompt hit
-                    st1, logits1 = ready
-                    ent = {"req": req, "prompt": prompt, "done": len(prompt),
-                           "resumed": False}
+        with self._trace.phase(ADMIT):
+            for h, host in enumerate(hosts):
+                sched = host.sched
+                for local in sched.free_slots():
+                    if not host.queue:
+                        break
+                    arrival, req = host.queue.pop(0)
+                    prompt = self._padded(req.prompt, run.prompt_len)
+                    ready = self._ready_state(req)
+                    if ready is not None:
+                        # prefilled elsewhere (disagg handoff): splice +
+                        # promote with zero local prefill work — the whole
+                        # prompt counts as cached on this host, exactly like
+                        # a full-prompt hit
+                        st1, logits1 = ready
+                        ent = {"req": req, "prompt": prompt,
+                               "done": len(prompt), "resumed": False}
+                        sched.hold(local, req, arrival, run.tick,
+                                   prompt_tokens=len(prompt),
+                                   cached_tokens=len(prompt))
+                        sched.stats[req.id].update(host=h,
+                                                   admit_wall=self._now())
+                        self._promote(run, h, local, ent, logits1, st1)
+                        continue
+                    g = h * K + local
+                    offset, pstate, plogits = self._ops_lookup(prompt, h)
+                    remaining = len(prompt) - offset
+                    # per-request boundary snapshots are only worth caching
+                    # when they EXTEND a known shared prefix (a unique
+                    # prompt's boundaries have ~zero hit probability and
+                    # would churn the LRU); warm_prefix covers first-contact
+                    # system prompts
+                    ent = {"req": req, "prompt": prompt, "done": offset,
+                           "resumed": offset > 0}
                     sched.hold(local, req, arrival, run.tick,
-                               prompt_tokens=len(prompt),
-                               cached_tokens=len(prompt))
-                    sched.stats[req.id]["host"] = h
-                    self._promote(run, h, local, ent, logits1, st1)
-                    continue
-                g = h * K + local
-                offset, pstate, plogits = self._ops_lookup(prompt, h)
-                remaining = len(prompt) - offset
-                # per-request boundary snapshots are only worth caching
-                # when they EXTEND a known shared prefix (a unique
-                # prompt's boundaries have ~zero hit probability and
-                # would churn the LRU); warm_prefix covers first-contact
-                # system prompts
-                ent = {"req": req, "prompt": prompt, "done": offset,
-                       "resumed": offset > 0}
-                sched.hold(local, req, arrival, run.tick,
-                           prompt_tokens=len(prompt), cached_tokens=offset)
-                sched.stats[req.id]["host"] = h
-                if remaining == 0:
-                    # full-prompt cache hit: the stored last-token logits
-                    # stand in for the skipped prefill
-                    self._promote(run, h, local, ent, plogits, pstate)
-                elif chunk_size and coalesce:
-                    # incremental admission via the batched dispatch
-                    # below (which promotes a <= one-chunk remainder
-                    # within this same tick): seed the slot's
-                    # prefill-pool row
-                    if run.prefill_pool is None:
-                        run.prefill_pool = T.init_decode_state(cfg, B,
-                                                               self.max_len)
-                    if pstate is None:
-                        run.prefill_pool = self._ops_insert(
-                            run.prefill_pool, self._fresh_template(), g)
-                    else:
-                        run.prefill_pool = self._ops_insert(run.prefill_pool,
-                                                            pstate, g)
-                    host.pending[local] = ent
-                elif chunk_size:
-                    # legacy one-request-per-tick admission (batch-1
-                    # states; single-host only — the sharded engine
-                    # always coalesces)
-                    ent["state"] = (pstate if pstate is not None
-                                    else self._fresh_template())
-                    host.pending[local] = ent
-                else:  # monolithic admission (single-host only)
-                    if pstate is None:
-                        logits1, st1 = self._prefill(
-                            self.params, inputs=jnp.asarray(prompt[None]))
-                    else:
-                        logits1, st1 = self._prefill_chunk(
-                            self.params,
-                            inputs=jnp.asarray(prompt[None, offset:]),
-                            state=pstate)
-                    self._ops_cache_insert(prompt, len(prompt), st1,
-                                           logits1, h)
-                    self._promote(run, h, local, ent, logits1, st1)
+                               prompt_tokens=len(prompt), cached_tokens=offset)
+                    sched.stats[req.id].update(host=h, admit_wall=self._now())
+                    if remaining == 0:
+                        # full-prompt cache hit: the stored last-token logits
+                        # stand in for the skipped prefill
+                        self._promote(run, h, local, ent, plogits, pstate)
+                    elif chunk_size and coalesce:
+                        # incremental admission via the batched dispatch
+                        # below (which promotes a <= one-chunk remainder
+                        # within this same tick): seed the slot's
+                        # prefill-pool row
+                        if run.prefill_pool is None:
+                            run.prefill_pool = T.init_decode_state(
+                                cfg, B, self.max_len)
+                        if pstate is None:
+                            run.prefill_pool = self._ops_insert(
+                                run.prefill_pool, self._fresh_template(), g)
+                        else:
+                            run.prefill_pool = self._ops_insert(
+                                run.prefill_pool, pstate, g)
+                        host.pending[local] = ent
+                    elif chunk_size:
+                        # legacy one-request-per-tick admission (batch-1
+                        # states; single-host only — the sharded engine
+                        # always coalesces)
+                        ent["state"] = (pstate if pstate is not None
+                                        else self._fresh_template())
+                        host.pending[local] = ent
+                    else:  # monolithic admission (single-host only)
+                        with self._trace.prefill(1, remaining, remaining):
+                            if pstate is None:
+                                logits1, st1 = self._prefill(
+                                    self.params,
+                                    inputs=jnp.asarray(prompt[None]))
+                            else:
+                                logits1, st1 = self._prefill_chunk(
+                                    self.params,
+                                    inputs=jnp.asarray(prompt[None, offset:]),
+                                    state=pstate)
+                        self._ops_cache_insert(prompt, len(prompt), st1,
+                                               logits1, h)
+                        self._promote(run, h, local, ent, logits1, st1)
 
         # --- mixed step: ONE masked chunk dispatch advances every pending
         # admission (coalesce=True). Two static shapes only: a lone
@@ -939,61 +969,69 @@ class ServeEngine:
             ent = host.pending[local]
             g = h * K + local
             n = min(chunk_size, len(ent["prompt"]) - ent["done"])
-            buf = np.zeros((1, chunk_size), np.int32)
-            buf[0, :n] = ent["prompt"][ent["done"]:ent["done"] + n]
-            st1 = self._ops_extract(run.prefill_pool, g)
-            logits1, st1 = self._prefill_chunk(
-                self.params, inputs=jnp.asarray(buf), state=st1,
-                valid_len=jnp.asarray([n], np.int32))
-            ent["done"] += n
-            finished = ent["done"] == len(ent["prompt"])
-            if ent["resumed"] or finished:
-                self._ops_cache_insert(ent["prompt"], ent["done"], st1,
-                                       logits1, h)
-            if finished:
-                del host.pending[local]
-                self._promote(run, h, local, ent, logits1, st1)
-            else:
-                run.prefill_pool = self._ops_insert(run.prefill_pool, st1, g)
+            with self._trace.prefill(1, chunk_size, n):
+                buf = np.zeros((1, chunk_size), np.int32)
+                buf[0, :n] = ent["prompt"][ent["done"]:ent["done"] + n]
+                st1 = self._ops_extract(run.prefill_pool, g)
+                logits1, st1 = self._prefill_chunk(
+                    self.params, inputs=jnp.asarray(buf), state=st1,
+                    valid_len=jnp.asarray([n], np.int32))
+                ent["done"] += n
+                finished = ent["done"] == len(ent["prompt"])
+                if ent["resumed"] or finished:
+                    self._ops_cache_insert(ent["prompt"], ent["done"], st1,
+                                           logits1, h)
+                if finished:
+                    del host.pending[local]
+                    self._promote(run, h, local, ent, logits1, st1)
+                else:
+                    run.prefill_pool = self._ops_insert(run.prefill_pool,
+                                                        st1, g)
         elif n_pending and coalesce:
-            chunk_tok = np.zeros((B, chunk_size), np.int32)
             valid = np.zeros((B,), np.int32)
             for h, host in enumerate(hosts):
                 for local, ent in host.pending.items():
-                    g = h * K + local
-                    n = min(chunk_size, len(ent["prompt"]) - ent["done"])
-                    chunk_tok[g, :n] = ent["prompt"][ent["done"]:ent["done"] + n]
-                    valid[g] = n
-            logits_all, run.prefill_pool = self._ops_prefill_pool(
-                self.params, jnp.asarray(chunk_tok), run.prefill_pool,
-                jnp.asarray(valid))
-            for h, host in enumerate(hosts):
-                for local in list(host.pending):
-                    ent = host.pending[local]
-                    g = h * K + local
-                    ent["done"] += int(valid[g])
-                    finished = ent["done"] == len(ent["prompt"])
-                    if ent["resumed"] or finished:
-                        # boundary snapshot -> the owning host's shard
-                        st1 = self._ops_extract(run.prefill_pool, g)
-                        self._ops_cache_insert(
-                            ent["prompt"], ent["done"], st1,
-                            logits_all[g:g + 1], h)
-                    if finished:
-                        del host.pending[local]
-                        self._promote(run, h, local, ent,
-                                      logits_all[g:g + 1], st1)
+                    valid[h * K + local] = min(
+                        chunk_size, len(ent["prompt"]) - ent["done"])
+            with self._trace.prefill(B, chunk_size, int(valid.sum())):
+                chunk_tok = np.zeros((B, chunk_size), np.int32)
+                for h, host in enumerate(hosts):
+                    for local, ent in host.pending.items():
+                        g = h * K + local
+                        done, n = ent["done"], valid[g]
+                        chunk_tok[g, :n] = ent["prompt"][done:done + n]
+                logits_all, run.prefill_pool = self._ops_prefill_pool(
+                    self.params, jnp.asarray(chunk_tok), run.prefill_pool,
+                    jnp.asarray(valid))
+                for h, host in enumerate(hosts):
+                    for local in list(host.pending):
+                        ent = host.pending[local]
+                        g = h * K + local
+                        ent["done"] += int(valid[g])
+                        finished = ent["done"] == len(ent["prompt"])
+                        if ent["resumed"] or finished:
+                            # boundary snapshot -> the owning host's shard
+                            st1 = self._ops_extract(run.prefill_pool, g)
+                            self._ops_cache_insert(
+                                ent["prompt"], ent["done"], st1,
+                                logits_all[g:g + 1], h)
+                        if finished:
+                            del host.pending[local]
+                            self._promote(run, h, local, ent,
+                                          logits_all[g:g + 1], st1)
         # --- ...or one batch-1 chunk per pending slot (legacy path,
         # single-host only) ---------------------------------------------
         elif n_pending:
             host = hosts[0]
             for local in list(host.pending):
                 ent = host.pending[local]
-                n = min(chunk_size, len(ent["prompt"]) - ent["done"])
-                logits1, ent["state"] = self._prefill_chunk(
-                    self.params,
-                    inputs=jnp.asarray(ent["prompt"][None, ent["done"]:ent["done"] + n]),
-                    state=ent["state"])
+                done = ent["done"]
+                n = min(chunk_size, len(ent["prompt"]) - done)
+                with self._trace.prefill(1, n, n):
+                    logits1, ent["state"] = self._prefill_chunk(
+                        self.params,
+                        inputs=jnp.asarray(ent["prompt"][None, done:done + n]),
+                        state=ent["state"])
                 ent["done"] += n
                 if ent["resumed"] or ent["done"] == len(ent["prompt"]):
                     self._ops_cache_insert(ent["prompt"], ent["done"],
@@ -1015,31 +1053,35 @@ class ServeEngine:
         hosts, K = run.hosts, run.K
         decoded = run.any_live()
         if decoded and run.spec is not None:
-            caps = jnp.asarray(self._row_caps(hosts, K))
-            self._spec_tick(run, caps)
+            self._spec_tick(run)
         elif decoded:
-            caps = jnp.asarray(self._row_caps(hosts, K))
-            run.keys, subs = self._split(run.keys)
-            logits, run.pool = self._ops_decode(
-                self.params, jnp.asarray(run.tok), run.pool, caps)
-            nxt = np.array(self._sample(logits, subs, jnp.asarray(run.temps)))
-            run.tick += 1
-            now = self._now()
-            for h, host in enumerate(hosts):
-                sched = host.sched
-                row = nxt[h * K:(h + 1) * K]
-                new_live, new_emitted = advance_slots(
-                    row, sched.live, sched.emitted, sched.budgets,
-                    self.eos_id)
-                for local in np.flatnonzero(sched.live):
-                    rid = sched.req[local].id
-                    run.results[rid].append(int(row[local]))
-                    sched.stats[rid]["token_walls"].append(now)
-                sched.emitted = new_emitted
-                for local in np.flatnonzero(sched.live & ~new_live):
-                    sched.release(local, run.tick)
-                    run.pool = self._ops_reset(run.pool, h * K + local)
-            run.tok = nxt
+            live = sum(int(h_.sched.live.sum()) for h_ in hosts)
+            with self._trace.decode(live):
+                caps = jnp.asarray(self._row_caps(hosts, K))
+                run.keys, subs = self._split(run.keys)
+                logits, run.pool = self._ops_decode(
+                    self.params, jnp.asarray(run.tok), run.pool, caps)
+                sampled = self._sample(logits, subs, jnp.asarray(run.temps))
+                with self._trace.sync():
+                    nxt = np.array(sampled)
+            with self._trace.phase(RELEASE):
+                run.tick += 1
+                now = self._now()
+                for h, host in enumerate(hosts):
+                    sched = host.sched
+                    row = nxt[h * K:(h + 1) * K]
+                    new_live, new_emitted = advance_slots(
+                        row, sched.live, sched.emitted, sched.budgets,
+                        self.eos_id)
+                    for local in np.flatnonzero(sched.live):
+                        rid = sched.req[local].id
+                        run.results[rid].append(int(row[local]))
+                        sched.stats[rid]["token_walls"].append(now)
+                    sched.emitted = new_emitted
+                    for local in np.flatnonzero(sched.live & ~new_live):
+                        sched.release(local, run.tick)
+                        run.pool = self._ops_reset(run.pool, h * K + local)
+                run.tok = nxt
         elif run.any_pending():
             run.tick += 1  # prefill-only tick (nothing decoding yet)
         return decoded
@@ -1047,32 +1089,34 @@ class ServeEngine:
     def _serve_tick(self, run):
         """One full scheduler tick: idle fast-forward -> route arrivals ->
         admission phase -> decode phase -> SLO ladder -> cache TTL clock."""
-        tick_was = run.tick
-        if (run.fast_forward and not run.any_live() and not run.any_pending()
-                and not run.any_queued() and run.queue
-                and run.queue[0][0] > run.tick):
-            run.tick = run.queue[0][0]  # idle: fast-forward to next arrival
-            # sweep the TTL clock across the jump BEFORE this tick's
-            # admission lookups: an entry idle past its TTL expires
-            # honestly, instead of being hit and then evicted by a
-            # stale-clock sweep at the end of the loop body
-            self._cache_tick(run.tick - tick_was)
+        with self._trace.phase(TICK, tick=run.tick):
             tick_was = run.tick
+            if (run.fast_forward and not run.any_live()
+                    and not run.any_pending() and not run.any_queued()
+                    and run.queue and run.queue[0][0] > run.tick):
+                # idle: fast-forward to the next arrival
+                run.tick = run.queue[0][0]
+                # sweep the TTL clock across the jump BEFORE this tick's
+                # admission lookups: an entry idle past its TTL expires
+                # honestly, instead of being hit and then evicted by a
+                # stale-clock sweep at the end of the loop body
+                self._cache_tick(run.tick - tick_was)
+                tick_was = run.tick
 
-        self._route_arrivals(run.hosts, run.queue, run.tick)
-        self._tick_admission(run)
-        decoded = self._tick_decode(run)
+            self._route_arrivals(run.hosts, run.queue, run.tick)
+            self._tick_admission(run)
+            decoded = self._tick_decode(run)
 
-        if self.slo_degrade:
-            gap_ms = None
-            if decoded:
-                now_slo = self._now()
-                if self._slo_last_wall is not None:
-                    gap_ms = (now_slo - self._slo_last_wall) * 1e3
-                self._slo_last_wall = now_slo
-            self._slo_update(run.hosts, gap_ms)
+            if self.slo_degrade:
+                gap_ms = None
+                if decoded:
+                    now_slo = self._now()
+                    if self._slo_last_wall is not None:
+                        gap_ms = (now_slo - self._slo_last_wall) * 1e3
+                    self._slo_last_wall = now_slo
+                self._slo_update(run.hosts, gap_ms)
 
-        self._cache_tick(run.tick - tick_was)
+            self._cache_tick(run.tick - tick_was)
 
     def _serve_finish(self, run, return_stats):
         out = {rid: np.array(toks, np.int32)
@@ -1105,7 +1149,7 @@ class ServeEngine:
         return self._serve_finish(run, return_stats)
 
     # ------------------------------------------------------------ speculative
-    def _spec_tick(self, run, caps=None):
+    def _spec_tick(self, run):
         """One draft-verify-accept round (DESIGN.md §Serving): draft k
         tokens per live row, score the whole window in ONE ``spec_verify``
         dispatch, emit every accepted token plus the model's bonus token,
@@ -1122,57 +1166,64 @@ class ServeEngine:
         adapt = run.spec_adapt
         L = self.spec_k + 1
         live_mask = np.concatenate([h_.sched.live for h_ in hosts])
-        inputs = np.zeros((B, L), np.int32)
-        inputs[:, 0] = tok
-        inputs[:, 1:] = spec.propose(tok, live_mask)
-        # cap the window at the remaining budget so a row never consumes
-        # tokens past prompt+max_new_tokens (the dead-row valid=0 contract
-        # handles everything else); live rows always get >= 1
-        valid = np.zeros(B, np.int32)
-        for h, host in enumerate(hosts):
-            sched = host.sched
-            for local in np.flatnonzero(sched.live):
-                g = h * K + local
-                remaining = int(sched.budgets[local] - sched.emitted[local])
-                win = L if adapt is None else min(L, 1 + adapt.k_for(g))
-                valid[g] = min(win, remaining)
-        greedy, commit, run.pool = self._ops_verify(
-            self.params, jnp.asarray(inputs), jnp.asarray(valid), run.pool,
-            caps)
-        greedy = np.asarray(greedy)
-        commit = np.asarray(commit)
-        run.tick += 1
-        now = self._now()
-        sstats = self.spec_stats
-        sstats["verify_calls"] += 1
-        for h, host in enumerate(hosts):
-            sched = host.sched
-            for local in np.flatnonzero(sched.live):
-                g = h * K + local
-                rid = sched.req[local].id
-                sstats["drafted"] += int(valid[g]) - 1
-                sstats["accepted"] += int(commit[g]) - 1
-                if adapt is not None and valid[g] > 1:
-                    adapt.observe(g, int(valid[g]) - 1, int(commit[g]) - 1)
-                emitted_now = []
-                for t in greedy[g, :commit[g]]:
-                    emitted_now.append(int(t))
-                    if int(t) == self.eos_id:
-                        break  # tokens past EOS are never emitted
-                results[rid].extend(emitted_now)
-                sched.stats[rid]["token_walls"].extend([now] * len(emitted_now))
-                sched.emitted[local] += len(emitted_now)
-                sstats["emitted"] += len(emitted_now)
-                if (sched.emitted[local] >= sched.budgets[local]
-                        or emitted_now[-1] == self.eos_id):
-                    sched.release(local, run.tick)
-                    run.pool = self._ops_reset(run.pool, g)
-                else:
-                    tok[g] = emitted_now[-1]
-                    spec.on_emit(g, emitted_now)
-        # model-draft bookkeeping: roll the draft pool forward by exactly
-        # the committed tokens (no-op for the host-side n-gram draft)
-        spec.commit(inputs, commit)
+        with self._trace.decode(int(live_mask.sum())):
+            caps = jnp.asarray(self._row_caps(hosts, K))
+            inputs = np.zeros((B, L), np.int32)
+            inputs[:, 0] = tok
+            inputs[:, 1:] = spec.propose(tok, live_mask)
+            # cap the window at the remaining budget so a row never consumes
+            # tokens past prompt+max_new_tokens (the dead-row valid=0
+            # contract handles everything else); live rows always get >= 1
+            valid = np.zeros(B, np.int32)
+            for h, host in enumerate(hosts):
+                sched = host.sched
+                for local in np.flatnonzero(sched.live):
+                    g = h * K + local
+                    remaining = int(sched.budgets[local]
+                                    - sched.emitted[local])
+                    win = L if adapt is None else min(L, 1 + adapt.k_for(g))
+                    valid[g] = min(win, remaining)
+            greedy, commit, run.pool = self._ops_verify(
+                self.params, jnp.asarray(inputs), jnp.asarray(valid), run.pool,
+                caps)
+            with self._trace.sync():
+                greedy = np.asarray(greedy)
+                commit = np.asarray(commit)
+        with self._trace.phase(RELEASE):
+            run.tick += 1
+            now = self._now()
+            sstats = self.spec_stats
+            sstats["verify_calls"] += 1
+            for h, host in enumerate(hosts):
+                sched = host.sched
+                for local in np.flatnonzero(sched.live):
+                    g = h * K + local
+                    rid = sched.req[local].id
+                    sstats["drafted"] += int(valid[g]) - 1
+                    sstats["accepted"] += int(commit[g]) - 1
+                    if adapt is not None and valid[g] > 1:
+                        adapt.observe(g, int(valid[g]) - 1,
+                                      int(commit[g]) - 1)
+                    emitted_now = []
+                    for t in greedy[g, :commit[g]]:
+                        emitted_now.append(int(t))
+                        if int(t) == self.eos_id:
+                            break  # tokens past EOS are never emitted
+                    results[rid].extend(emitted_now)
+                    sched.stats[rid]["token_walls"].extend(
+                        [now] * len(emitted_now))
+                    sched.emitted[local] += len(emitted_now)
+                    sstats["emitted"] += len(emitted_now)
+                    if (sched.emitted[local] >= sched.budgets[local]
+                            or emitted_now[-1] == self.eos_id):
+                        sched.release(local, run.tick)
+                        run.pool = self._ops_reset(run.pool, g)
+                    else:
+                        tok[g] = emitted_now[-1]
+                        spec.on_emit(g, emitted_now)
+            # model-draft bookkeeping: roll the draft pool forward by
+            # exactly the committed tokens (no-op for the n-gram draft)
+            spec.commit(inputs, commit)
 
     def _cache_tick(self, n: int):
         """Advance the prefix cache's TTL clock by ``n`` scheduler ticks."""
