@@ -1,6 +1,6 @@
 """Serving §Perf — slot-level continuous batching vs the wave engine,
-chunked prefill admission, the prefix-state cache, the two-shape BATCHED
-admission path, speculative decoding, multi-host sharded serving, and
+chunked prefill admission, the prefix-state cache, the row-bucketed
+BATCHED admission path, speculative decoding, multi-host sharded serving, and
 disaggregated prefill/decode fleets.
 
 Eight traces are replayed; the first four through the same ``ServeEngine``:
@@ -20,8 +20,8 @@ Eight traces are replayed; the first four through the same ``ServeEngine``:
    residues, replayed COLD (fresh jit caches) through both admission
    paths: the PR-2 one-request-per-tick path (one batch-1 dispatch per
    pending slot per tick, each distinct tail residue a fresh compile) vs
-   the coalesced two-shape path (ONE [slots, chunk] masked dispatch per
-   tick, exactly one prefill compile). Reports prefill compile counts,
+   the coalesced row-bucketed path (ONE [Kb, chunk] masked dispatch per
+   tick, one prefill compile per row bucket). Reports prefill compile counts,
    admission throughput (prefill tokens/s), and the co-resident decode
    inter-token p99 gap — the compile stalls the legacy path takes
    mid-trace land exactly on those gaps.
@@ -721,7 +721,7 @@ def main(fast: bool = False):
          f"flops_skipped={pc_rows['cached']['flops_skipped_frac']:.3f};"
          f"sys_len={sys_len}")
 
-    # --- two-shape batched admission vs the PR-2 one-request-per-tick path
+    # --- row-bucketed batched admission vs the PR-2 one-request-per-tick path
     bchunk = _admission_chunk(fast)
     blong = 512 if fast else 4096
     breqs, barrivals, bshort = concurrent_long_prompt_trace(

@@ -8,8 +8,9 @@
 scheduler; ``--mode wave`` is the legacy admission-wave baseline.
 ``--prefill-chunk N`` admits long prompts incrementally (N tokens per tick,
 interleaved with decode); all co-pending admissions advance in one batched
-masked dispatch per tick at two static shapes (``--sequential-admission``
-reverts to the one-request-per-tick path with natural-length tails).
+masked dispatch per tick, sized to the pending rows' power-of-two row
+bucket (``--sequential-admission`` reverts to the one-request-per-tick
+path with natural-length tails).
 ``--system-prompt-len K`` prepends a shared K-token system prompt to every
 request and serves it through the prefix cache, reporting the prefill
 FLOPs skipped; ``--prefix-cache-max-mb`` switches the cache to bytes-aware

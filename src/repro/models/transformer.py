@@ -760,43 +760,69 @@ def decode_step(params: dict, cfg: ModelConfig, token_t: jax.Array, state: dict,
 # A decode-state pytree built by init_decode_state(cfg, batch=n_slots, ...) is
 # a POOL: every leaf carries the slot axis — axis 0 normally, axis 1 for
 # scan-over-layers groups (whose leaves are stacked [count, batch, ...]).
-# The helpers below splice single-sequence states in and out of a pool along
-# that axis, uniformly across attention KV caches, STLT h_re/h_im, hann ring
-# buffers, rg-LRU / xLSTM recurrent states, and all per-sequence positions.
+# The helpers below move rows (one sequence's state each) in and out of a
+# pool along that axis, uniformly across attention KV caches, STLT
+# h_re/h_im, hann ring buffers, rg-LRU / xLSTM recurrent states, and all
+# per-sequence positions.
 
 
 def _slot_axis(count: int) -> int:
     return 1 if count > 1 else 0
 
 
-def insert_slot(pool: dict, state: dict, slot, cfg: ModelConfig) -> dict:
-    """Splice a batch-1 decode state (e.g. fresh from ``prefill``) into slot
-    ``slot`` of a pooled decode state. jit-safe; ``slot`` may be traced."""
-    layers = []
-    for (btype, count), pl, sl in zip(
-        execution_plan(cfg), pool["layers"], state["layers"]
-    ):
-        ax = _slot_axis(count)
-        layers.append(jax.tree_util.tree_map(
-            lambda p, s: jax.lax.dynamic_update_slice_in_dim(
-                p, s.astype(p.dtype), slot, axis=ax),
-            pl, sl,
-        ))
-    pos = jax.lax.dynamic_update_slice_in_dim(
-        pool["pos"], state["pos"].astype(pool["pos"].dtype), slot, axis=0)
-    return {"layers": layers, "pos": pos}
-
-
-def extract_slot(pool: dict, slot, cfg: ModelConfig) -> dict:
-    """The inverse of ``insert_slot``: the batch-1 decode state of one slot."""
+def take_slots(pool: dict, idx: jax.Array, cfg: ModelConfig) -> dict:
+    """The rows ``idx`` ([k] ints) of a pooled decode state, as a batch-k
+    state in that order."""
     layers = []
     for (btype, count), pl in zip(execution_plan(cfg), pool["layers"]):
         ax = _slot_axis(count)
         layers.append(jax.tree_util.tree_map(
-            lambda p: jax.lax.dynamic_slice_in_dim(p, slot, 1, axis=ax), pl,
-        ))
+            lambda p: jnp.take(p, idx, axis=ax, mode="clip"), pl))
     return {"layers": layers,
-            "pos": jax.lax.dynamic_slice_in_dim(pool["pos"], slot, 1, axis=0)}
+            "pos": jnp.take(pool["pos"], idx, axis=0, mode="clip")}
+
+
+def put_slots(pool: dict, rows: dict, idx: jax.Array, cfg: ModelConfig) -> dict:
+    """The inverse of ``take_slots``: write the batch-k state ``rows`` back
+    at slots ``idx``. The indices must be distinct: a repeated index would
+    make the scatter's result depend on the order of its writes."""
+    def put(ax):
+        return lambda p, r: p.at[(slice(None),) * ax + (idx,)].set(
+            r.astype(p.dtype), unique_indices=True, mode="promise_in_bounds")
+
+    layers = []
+    for (btype, count), pl, rl in zip(
+        execution_plan(cfg), pool["layers"], rows["layers"]
+    ):
+        layers.append(jax.tree_util.tree_map(put(_slot_axis(count)), pl, rl))
+    return {"layers": layers, "pos": put(0)(pool["pos"], rows["pos"])}
+
+
+def prefill_chunk_rows(params: dict, cfg: ModelConfig, inputs: jax.Array,
+                       pool: dict, idx: jax.Array, valid_len: jax.Array):
+    """``prefill_chunk`` over the rows ``idx`` ([k] distinct slots) of a
+    pooled state: gather them, advance them by ``inputs`` [k, N] with
+    ``valid_len`` [k], scatter them back. Returns (k last-token logits rows,
+    each [1, V], new pool): a caller takes a row with no slice program of
+    its own for each row and k. Slots outside ``idx`` are untouched, and a
+    row of ``idx`` with ``valid_len`` 0 comes back bit for bit, so a
+    dispatch sized to the rows that have prompt tokens pays for those rows
+    and not for the pool."""
+    logits, rows = prefill_chunk(params, cfg, inputs,
+                                 take_slots(pool, idx, cfg), valid_len)
+    return (tuple(logits[i:i + 1] for i in range(logits.shape[0])),
+            put_slots(pool, rows, idx, cfg))
+
+
+def insert_slot(pool: dict, state: dict, slot, cfg: ModelConfig) -> dict:
+    """Splice a batch-1 decode state (e.g. fresh from ``prefill``) into slot
+    ``slot`` of a pooled decode state. jit-safe; ``slot`` may be traced."""
+    return put_slots(pool, state, jnp.reshape(slot, (1,)), cfg)
+
+
+def extract_slot(pool: dict, slot, cfg: ModelConfig) -> dict:
+    """The inverse of ``insert_slot``: the batch-1 decode state of one slot."""
+    return take_slots(pool, jnp.reshape(slot, (1,)), cfg)
 
 
 def reset_slot(pool: dict, slot, cfg: ModelConfig, max_len: int) -> dict:

@@ -40,17 +40,20 @@ pass — the Pallas kernel included — so chunked admission pays exactly one
 scan pass per chunk, with no linearity-folded free-response/final-state
 correction passes (``benchmarks/kernels.py`` measures the gap).
 
-Chunked admission is a TWO-SHAPE program (DESIGN.md §Serving): every chunk
-— tail chunks included — is padded to ``prefill_chunk`` and carries a
-per-row ``valid_len`` mask, and ALL co-pending admissions advance in ONE
-masked dispatch per tick. Pending prefills live in a second slot-shaped
-pool (``prefill pool``); the dispatch is bucketed to exactly two static
-shapes — ``[1, prefill_chunk]`` when one slot is pending (also the
-``warm_prefix`` shape) and ``[slots, prefill_chunk]`` when several co-pend
-— so a serve trace over prompts of arbitrary lengths compiles exactly two
-prefill programs, ever. (The PR-2 engine compiled one program per distinct
-``prompt_len % chunk`` and advanced one request per jitted call; that path
-is kept as ``coalesce=False`` for parity tests and benchmarks.)
+Chunked admission is ROW-BUCKETED (DESIGN.md §Serving): every chunk — tail
+chunks included — is padded to ``prefill_chunk`` and carries a per-row
+``valid_len`` mask, and ALL co-pending admissions advance in ONE masked
+dispatch per tick. Pending prefills live in a second slot-shaped pool
+(``prefill pool``); the dispatch (``transformer.prefill_chunk_rows``)
+gathers the k pending rows of it, padded with distinct idle rows to the
+smallest row bucket Kb in 1, 2, 4, ..., ``slots`` that holds k, runs
+``[Kb, prefill_chunk]`` and scatters the rows back — so a tick pays for
+the rows that have prompt tokens, not for the pool, and a serve trace over
+prompts of arbitrary lengths compiles one prefill program per bucket,
+ever, all of them before the first dispatch. (The PR-2 engine compiled one
+program per distinct ``prompt_len % chunk`` and advanced one request per
+jitted call; that path is kept as ``coalesce=False`` for parity tests and
+benchmarks.)
 
 A :class:`PrefixCache` (``prefix_cache=``) snapshots the O(S*d) streaming
 state at chunk boundaries keyed by prompt-prefix hash, so requests sharing
@@ -93,13 +96,19 @@ from repro.serving.sampler import (
 from repro.serving.trace import ADMIT, PROMOTE, RELEASE, TICK, TickTrace
 
 
-def _jit(fn, **bound):
+def _jit(fn, donate_argnames=(), **bound):
     """``jax.jit`` of ``fn`` with keyword arguments bound, named after
     ``fn``: a ``functools.partial`` has no name of its own, so its program
     would show as ``jit__unknown`` in a profiler trace, not ``jit_<fn>``."""
     p = partial(fn, **bound)
     p.__name__ = fn.__name__
-    return jax.jit(p)
+    return jax.jit(p, donate_argnames=donate_argnames)
+
+
+def row_buckets(slots: int) -> list[int]:
+    """Row counts of the co-pending prefill dispatch over a pool of
+    ``slots``: the powers of two below ``slots``, then ``slots``."""
+    return sorted({min(1 << i, slots) for i in range(slots.bit_length() + 1)})
 
 
 @dataclasses.dataclass
@@ -374,6 +383,11 @@ class ServeEngine:
         self.prefix_cache = prefix_cache
         self._prefill = _jit(T.prefill, cfg=cfg, max_len=max_len)
         self._prefill_chunk = _jit(T.prefill_chunk, cfg=cfg)
+        self._prefill_rows = _jit(T.prefill_chunk_rows, donate_argnames="pool",
+                                  cfg=cfg)
+        # compiled _prefill_rows by (slots, chunk, matmul precision), then
+        # by row bucket (_ops_prefill_rows)
+        self._rows_programs: dict = {}
         self._step = _jit(T.decode_step, cfg=cfg)
         self._verify = _jit(T.spec_verify, cfg=cfg)
         self._insert = _jit(T.insert_slot, cfg=cfg)
@@ -438,10 +452,11 @@ class ServeEngine:
 
         ``coalesce`` (default True) advances ALL co-pending admissions with
         one batched masked ``prefill_chunk`` dispatch per tick — tail
-        chunks padded to ``prefill_chunk`` with per-row ``valid_len``,
-        bucketed to the two static shapes [1, chunk] / [slots, chunk] — so
-        chunked admission compiles exactly two prefill programs regardless
-        of prompt lengths. ``coalesce=False`` keeps the legacy
+        chunks padded to ``prefill_chunk`` with per-row ``valid_len``, the
+        k pending rows padded to the row bucket [Kb, chunk], Kb the
+        smallest of 1, 2, 4, ..., ``slots`` that holds k — so chunked
+        admission compiles one prefill program per bucket regardless of
+        prompt lengths. ``coalesce=False`` keeps the legacy
         one-request-per-tick path (one batch-1 dispatch per pending slot,
         tail chunks jitted at their natural length); both paths are
         token-exact vs each other and vs monolithic admission.
@@ -548,8 +563,8 @@ class ServeEngine:
         eviction by per-request snapshots. Returns the number of tokens
         actually prefilled (0 on a full cache hit).
 
-        Two-shape contract: the tail remainder is masked-prefilled at the
-        padded [1, chunk] shape (per-row ``valid_len``), so warming never
+        Masked-chunk contract: the tail remainder is masked-prefilled at
+        the padded [1, chunk] shape (per-row ``valid_len``), so warming never
         truncates a non-boundary prefix to the last chunk boundary and never
         compiles a per-residue tail program — the EXACT-length entry always
         exists (regression-locked by tests/test_masked_prefill.py)."""
@@ -581,10 +596,12 @@ class ServeEngine:
     # overridable primitives; ShardedServeEngine swaps in its shard_map'd
     # dispatches and routing without touching the loop itself.
 
-    # the [1, chunk] lone-pending fast path (and the host-side ops it rides
-    # on) is a single-host economy: the sharded engine always dispatches the
-    # full per-shard pool shape so its trace stays two-shape
-    _fast_single_prefill = True
+    # the co-pending prefill dispatch takes only the pending rows, padded to
+    # a row bucket (_ops_prefill_rows): a gather and scatter over one
+    # device's pool. The sharded engine's rows sit on different devices, so
+    # it always dispatches the full per-shard pool shape and its trace
+    # stays two-shape
+    _row_buckets = True
 
     def _fresh_template(self):
         """Shared pristine batch-1 decode state (immutable pytree): seeds
@@ -606,6 +623,25 @@ class ServeEngine:
         """Full-pool masked chunk dispatch ([B, chunk] + per-row valid)."""
         return self._prefill_chunk(params, inputs=toks, state=state,
                                    valid_len=valid)
+
+    def _ops_prefill_rows(self, params, toks, pool, idx, valid):
+        """Masked chunk dispatch over the pool rows ``idx`` ([Kb, chunk] +
+        per-row valid; the pool is donated): (Kb logits rows, new pool).
+        The first dispatch over a pool of a given size compiles every row
+        bucket ahead of time, so no later mix of co-pending admissions
+        compiles inside a serve."""
+        key = (pool["pos"].shape[0], toks.shape[1],
+               jax.config.jax_default_matmul_precision)
+        if key not in self._rows_programs:
+            i32 = jnp.int32
+            self._rows_programs[key] = {
+                kb: self._prefill_rows.lower(
+                    params, inputs=jax.ShapeDtypeStruct((kb, key[1]), i32),
+                    pool=pool, idx=jax.ShapeDtypeStruct((kb,), i32),
+                    valid_len=jax.ShapeDtypeStruct((kb,), i32)).compile()
+                for kb in row_buckets(key[0])}
+        return self._rows_programs[key][toks.shape[0]](
+            params, inputs=toks, pool=pool, idx=idx, valid_len=valid)
 
     def _full_caps(self, b: int):
         """Cached full-S node-cap array: a cap == S row is the all-ones
@@ -955,73 +991,58 @@ class ServeEngine:
                         self._promote(run, h, local, ent, logits1, st1)
 
         # --- mixed step: ONE masked chunk dispatch advances every pending
-        # admission (coalesce=True). Two static shapes only: a lone
-        # pending slot advances at [1, chunk] (the warm_prefix shape —
-        # no point paying B-x the FLOPs for one row; single-host only),
-        # co-pending slots coalesce into the full [B, chunk] dispatch
-        # ([K, chunk] per shard).
-        n_pending = sum(len(h_.pending) for h_ in hosts)
-        if (n_pending == 1 and coalesce and B > 1
-                and self._fast_single_prefill):
-            h, host = next((h_i, h_) for h_i, h_ in enumerate(hosts)
-                           if h_.pending)
-            local, = host.pending
-            ent = host.pending[local]
-            g = h * K + local
-            n = min(chunk_size, len(ent["prompt"]) - ent["done"])
-            with self._trace.prefill(1, chunk_size, n):
-                buf = np.zeros((1, chunk_size), np.int32)
-                buf[0, :n] = ent["prompt"][ent["done"]:ent["done"] + n]
-                st1 = self._ops_extract(run.prefill_pool, g)
-                logits1, st1 = self._prefill_chunk(
-                    self.params, inputs=jnp.asarray(buf), state=st1,
-                    valid_len=jnp.asarray([n], np.int32))
-                ent["done"] += n
-                finished = ent["done"] == len(ent["prompt"])
-                if ent["resumed"] or finished:
+        # admission (coalesce=True). On one device's pool it takes only the
+        # k pending rows: [Kb, chunk], Kb the smallest of the row buckets
+        # 1, 2, 4, ..., slots that holds k, padded with distinct rows that
+        # are not pending (valid 0: bit-exact no-ops). The sharded engine
+        # dispatches the whole pool ([K, chunk] per shard).
+        pending = [(h * K + local, h, local, ent)
+                   for h, host in enumerate(hosts)
+                   for local, ent in host.pending.items()]
+        if pending and coalesce:
+            if self._row_buckets:
+                busy = {g for g, *_ in pending}
+                kb = next(b for b in row_buckets(B) if b >= len(busy))
+                idx = sorted(busy) + [g for g in range(B)
+                                      if g not in busy][:kb - len(busy)]
+            else:
+                idx = list(range(B))
+            at = {g: i for i, g in enumerate(idx)}  # slot -> dispatch row
+            valid = np.zeros(len(idx), np.int32)
+            for g, _, _, ent in pending:
+                valid[at[g]] = min(chunk_size,
+                                   len(ent["prompt"]) - ent["done"])
+            with self._trace.prefill(len(idx), chunk_size, int(valid.sum())):
+                chunk_tok = np.zeros((len(idx), chunk_size), np.int32)
+                for g, _, _, ent in pending:
+                    done, n = ent["done"], valid[at[g]]
+                    chunk_tok[at[g], :n] = ent["prompt"][done:done + n]
+                if self._row_buckets:
+                    rows, run.prefill_pool = self._ops_prefill_rows(
+                        self.params, jnp.asarray(chunk_tok), run.prefill_pool,
+                        jnp.asarray(np.array(idx, np.int32)),
+                        jnp.asarray(valid))
+                else:
+                    rows, run.prefill_pool = self._ops_prefill_pool(
+                        self.params, jnp.asarray(chunk_tok), run.prefill_pool,
+                        jnp.asarray(valid))
+                for g, h, local, ent in pending:
+                    ent["done"] += int(valid[at[g]])
+                    finished = ent["done"] == len(ent["prompt"])
+                    if not (ent["resumed"] or finished):
+                        continue
+                    logits1 = (rows[at[g]] if self._row_buckets
+                               else rows[g:g + 1])
+                    # boundary snapshot -> the owning host's shard
+                    st1 = self._ops_extract(run.prefill_pool, g)
                     self._ops_cache_insert(ent["prompt"], ent["done"], st1,
                                            logits1, h)
-                if finished:
-                    del host.pending[local]
-                    self._promote(run, h, local, ent, logits1, st1)
-                else:
-                    run.prefill_pool = self._ops_insert(run.prefill_pool,
-                                                        st1, g)
-        elif n_pending and coalesce:
-            valid = np.zeros((B,), np.int32)
-            for h, host in enumerate(hosts):
-                for local, ent in host.pending.items():
-                    valid[h * K + local] = min(
-                        chunk_size, len(ent["prompt"]) - ent["done"])
-            with self._trace.prefill(B, chunk_size, int(valid.sum())):
-                chunk_tok = np.zeros((B, chunk_size), np.int32)
-                for h, host in enumerate(hosts):
-                    for local, ent in host.pending.items():
-                        g = h * K + local
-                        done, n = ent["done"], valid[g]
-                        chunk_tok[g, :n] = ent["prompt"][done:done + n]
-                logits_all, run.prefill_pool = self._ops_prefill_pool(
-                    self.params, jnp.asarray(chunk_tok), run.prefill_pool,
-                    jnp.asarray(valid))
-                for h, host in enumerate(hosts):
-                    for local in list(host.pending):
-                        ent = host.pending[local]
-                        g = h * K + local
-                        ent["done"] += int(valid[g])
-                        finished = ent["done"] == len(ent["prompt"])
-                        if ent["resumed"] or finished:
-                            # boundary snapshot -> the owning host's shard
-                            st1 = self._ops_extract(run.prefill_pool, g)
-                            self._ops_cache_insert(
-                                ent["prompt"], ent["done"], st1,
-                                logits_all[g:g + 1], h)
-                        if finished:
-                            del host.pending[local]
-                            self._promote(run, h, local, ent,
-                                          logits_all[g:g + 1], st1)
+                    if finished:
+                        del hosts[h].pending[local]
+                        self._promote(run, h, local, ent, logits1, st1)
         # --- ...or one batch-1 chunk per pending slot (legacy path,
         # single-host only) ---------------------------------------------
-        elif n_pending:
+        elif pending:
             host = hosts[0]
             for local in list(host.pending):
                 ent = host.pending[local]

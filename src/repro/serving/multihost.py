@@ -219,10 +219,11 @@ class ShardedServeEngine(ServeEngine):
     # engine swaps in its shard_map'd dispatches, per-shard cache routing,
     # and least-loaded arrival routing, and inherits everything else.
 
-    # never take the [1, chunk] lone-pending shortcut: the sharded trace
-    # stays two-shape ([K, chunk] serve dispatches + the host-local
-    # [1, chunk] warm_prefix shape) regardless of admission patterns
-    _fast_single_prefill = False
+    # never size the prefill dispatch to the pending rows: they sit on
+    # different shards, so the sharded trace stays two-shape ([K, chunk]
+    # serve dispatches + the host-local [1, chunk] warm_prefix shape)
+    # regardless of admission patterns
+    _row_buckets = False
 
     def _ops_insert(self, pool, st1, g):
         return self._insert_sh(pool, st1, g)

@@ -26,7 +26,9 @@ The phases, by span name:
 A phase's seconds in ``stats`` are its own: time spent in a phase nested
 inside it counts to the inner phase only, so the seconds of one tick's
 phases add up to the tick, and ``serve.tick`` holds what no other phase
-covers. ``stats`` also counts the work of the phases (:data:`COUNTERS`) and
+covers. ``stats`` also counts the work of the phases (:data:`COUNTERS`),
+the prefill dispatches by their rows (``prefill_rows_by_bucket``, rows ->
+dispatches: how often each row bucket of the co-pending prefill ran), and
 keeps the longest tick with its phase split.
 """
 from __future__ import annotations
@@ -66,6 +68,7 @@ class TickTrace:
     def reset(self) -> dict:
         self.stats = dict.fromkeys(COUNTERS, 0)
         self.stats.update(dict.fromkeys(PHASES, 0.0))
+        self.stats["prefill_rows_by_bucket"] = {}   # rows -> dispatches
         self.stats["longest_tick_s"] = 0.0
         self.stats["longest_tick"] = {}       # phase -> seconds of that tick
         self._open: list = []                 # [seconds of nested phases]
@@ -84,6 +87,8 @@ class TickTrace:
         st["prefill_dispatches"] += 1
         st["prefill_valid_tokens"] += valid
         st["prefill_slot_tokens"] += rows * width
+        by_rows = st["prefill_rows_by_bucket"]
+        by_rows[rows] = by_rows.get(rows, 0) + 1
         return _Phase(self, PREFILL, {"rows": rows, "valid": valid})
 
     def decode(self, live: int) -> "_Phase":
@@ -114,7 +119,8 @@ class TickTrace:
 def summary(stats: dict) -> str:
     """One line for an operator: host ms a tick by phase (``other``: the
     tick outside every phase), syncs a tick, the share of prefill token
-    slots that carried prompt tokens, and the longest tick by phase."""
+    slots that carried prompt tokens, prefill dispatches by rows, and the
+    longest tick by phase."""
     ticks = max(stats["ticks"], 1)
 
     def by_phase(secs: dict, per: int) -> str:
@@ -130,7 +136,9 @@ def summary(stats: dict) -> str:
     return (f"{stats['ticks']} ticks, {host_ms:.3f} host ms a tick "
             f"({by_phase(stats, ticks)}); "
             f"{stats['host_syncs'] / ticks:.2f} syncs a tick; prefill valid "
-            f"share {valid} of {slots} slot tokens; longest tick "
+            f"share {valid} of {slots} slot tokens; prefill dispatches "
+            f"by rows {dict(sorted(stats['prefill_rows_by_bucket'].items()))}"
+            f"; longest tick "
             f"{stats['longest_tick_s'] * 1e3:.3f} ms "
             f"({by_phase(stats['longest_tick'], 1)})")
 

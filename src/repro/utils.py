@@ -169,8 +169,8 @@ def trace_probe(fn: Callable, log: list, name: str | None = None) -> Callable:
     it is jitted turns ``log`` into a compilation counter: each entry is
     ``(name, shape)`` where shape is taken from the ``inputs`` kwarg (or the
     first array argument). The serving compile-count regression tests and
-    benchmarks/serving.py use this to prove chunked admission is a
-    two-shape program.
+    benchmarks/serving.py use this to prove chunked admission compiles a
+    fixed set of programs whatever the prompt lengths.
     """
     import functools
 

@@ -42,7 +42,7 @@ def jit_trace_log(monkeypatch):
     (= XLA compilation) of a prefill program appends ``(name, inputs.shape)``
     to the returned list. Engines must be constructed inside the test (after
     the patch) for their ``jax.jit`` wrappers to pick up the probe — used by
-    the two-shape compile-count regression in test_masked_prefill.py."""
+    the row-bucket compile-count regression in test_masked_prefill.py."""
     from repro.models import transformer as T
     from repro.utils import trace_probe
 

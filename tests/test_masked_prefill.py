@@ -1,4 +1,4 @@
-"""Two-shape batched chunked prefill: valid-length masking parity suite.
+"""Batched chunked prefill: valid-length masking parity suite.
 
 Locks the serving contract of DESIGN.md §Serving:
 
@@ -14,12 +14,16 @@ Locks the serving contract of DESIGN.md §Serving:
   depths with different valid lengths matches each row's own batch-1
   prefill (the coalesced-admission data layout).
 * BATCHED-ADMISSION parity: a serve trace admitted through the coalesced
-  [slots, chunk] dispatch is token-exact vs the legacy one-request-per-tick
+  row-bucketed dispatch is token-exact vs the legacy one-request-per-tick
   path, tick for tick.
+* ROW BUCKETS: co-pending admissions dispatch at [Kb, chunk], Kb the
+  smallest power of two (or the slot count) that holds them, padded with
+  distinct rows that are not pending; pad and free rows of the prefill
+  pool come back bit for bit, per block kind.
 * COMPILE COUNT: a serve trace over >= 8 distinct ``prompt_len % chunk``
-  residues compiles exactly ONE prefill program ([slots, chunk]); adding a
-  ``warm_prefix`` contributes exactly one more ([1, chunk]) — chunked
-  admission is a two-shape program.
+  residues compiles exactly one prefill program per row bucket ([1, chunk],
+  [2, chunk], [4, chunk] for 4 slots); adding a ``warm_prefix`` contributes
+  exactly one more (its batch-1 [1, chunk]), and nothing re-traces after.
 * ``warm_prefix`` at a non-chunk-boundary length still registers the
   EXACT-length entry (the remainder is masked-prefilled, not truncated to
   the last boundary).
@@ -200,7 +204,7 @@ def test_heterogeneous_batch_rows_match_batch1(kind):
 
 
 # ---------------------------------------------------------------------------
-# engine-level: coalesced admission parity + the two-shape compile count
+# engine-level: coalesced admission parity, row buckets, the compile count
 # ---------------------------------------------------------------------------
 
 
@@ -214,7 +218,7 @@ def _residue_requests(cfg, chunk, n, rng, budget_base=3):
 
 
 def test_batched_admission_matches_one_per_tick():
-    """N requests admitted via the coalesced [slots, chunk] dispatch produce
+    """N requests admitted via the coalesced row-bucketed dispatch produce
     token-exact outputs — and identical admit/live/finish ticks — vs the
     legacy sequential one-request-per-tick path, and vs per-request
     generate."""
@@ -239,13 +243,70 @@ def test_batched_admission_matches_one_per_tick():
             assert stats_b[r.id][k] == stats_s[r.id][k], (r.id, k)
 
 
-def test_two_shape_compile_count(jit_trace_log):
+@pytest.mark.parametrize("kind", ["stlt", "stlt_hann", "attn", "local_attn",
+                                  "rglru", "xlstm", "scanned_stlt"])
+def test_row_bucket_pads_with_idle_rows(kind):
+    """Three co-pending admissions in an 8-slot pool dispatch at bucket 4
+    with one pad row: the served tokens are exact vs the one-per-tick path
+    and vs generate, every row outside the pending ones (the pad, a live
+    row's stale prefill state, the free rows) comes back bit for bit, and
+    no pad index repeats a pending row."""
+    cfg, params = _setup(kind)
+    eng = ServeEngine(params, cfg, max_len=MAX_LEN, prefill_chunk=CHUNK)
+    rng = np.random.default_rng(13)
+    # tick 0: A (4 chunks) and B (1 chunk) -> bucket 2; B goes live and
+    # leaves its final state in prefill row 1. tick 1: A, C, D pending ->
+    # bucket 4, padded with row 1
+    lengths, arrivals = [3 * CHUNK + 2, 5, 2 * CHUNK - 4, 7], [0, 0, 1, 1]
+    reqs = [Request(rng.integers(3, cfg.vocab, n).astype(np.int32), 4, id=i)
+            for i, n in enumerate(lengths)]
+    calls = []
+    dispatch = eng._ops_prefill_rows
+
+    def record(params, toks, pool, idx, valid):
+        before = jax.tree_util.tree_map(np.array, pool)  # pool is donated
+        logits, pool = dispatch(params, toks, pool, idx, valid)
+        calls.append((np.array(idx), np.array(valid), before,
+                      jax.tree_util.tree_map(np.array, pool)))
+        return logits, pool
+
+    eng._ops_prefill_rows = record
+    res_b, stats_b = eng.serve(reqs, slots=8, arrivals=arrivals,
+                               return_stats=True)
+    assert [(len(i), int((v == 0).sum())) for i, v, _, _ in calls] == [
+        (2, 0), (4, 1), (2, 0), (1, 0)]
+    for idx, valid, before, after in calls:
+        assert len(set(idx.tolist())) == len(idx), idx
+        advanced = set(idx[valid > 0].tolist())
+        assert not advanced & set(idx[valid == 0].tolist()), idx
+        for g in sorted(set(range(8)) - advanced):
+            for x, y in zip(
+                    jax.tree_util.tree_leaves(T.extract_slot(before, g, cfg)),
+                    jax.tree_util.tree_leaves(T.extract_slot(after, g, cfg))):
+                np.testing.assert_array_equal(
+                    np.asarray(x), np.asarray(y),
+                    err_msg=f"{kind}: row {g} moved (idx {idx}, valid {valid})")
+
+    res_s, stats_s = eng.serve(reqs, slots=8, arrivals=arrivals,
+                               return_stats=True, coalesce=False)
+    for r in reqs:
+        np.testing.assert_array_equal(
+            res_b[r.id], res_s[r.id],
+            err_msg=f"{kind}: request {r.id}: bucketed vs one-per-tick")
+        np.testing.assert_array_equal(
+            res_b[r.id], eng.generate(r.prompt[None], r.max_new_tokens)[0],
+            err_msg=f"{kind}: request {r.id}: bucketed vs generate")
+        for k in ("admit", "live", "finish"):
+            assert stats_b[r.id][k] == stats_s[r.id][k], (r.id, k)
+
+
+def test_bucket_compile_count(jit_trace_log):
     """A full chunked serve trace over 8 distinct tail residues compiles
-    exactly TWO prefill programs — [1, chunk] (a lone pending admission;
-    also the warm_prefix shape) and [slots, chunk] (co-pending admissions
-    coalesced) — and nothing else, ever: warm_prefix, prefix-cache resumes,
-    and further residues all reuse them. The monolithic ``prefill`` program
-    is never traced."""
+    exactly one prefill program per row bucket — [1, chunk], [2, chunk]
+    and [4, chunk] for 4 slots, all ahead of the first dispatch — and
+    nothing else: warm_prefix adds its batch-1 [1, chunk] program, and
+    prefix-cache resumes and further residues re-trace nothing. The
+    monolithic ``prefill`` program is never traced."""
     cfg, params = _setup("stlt")
     rng = np.random.default_rng(5)
     eng = ServeEngine(params, cfg, max_len=128, prefill_chunk=CHUNK,
@@ -255,22 +316,28 @@ def test_two_shape_compile_count(jit_trace_log):
     eng.serve(reqs, slots=4, arrivals=[0, 0, 2, 2, 5, 9, 12, 12])
 
     def prefills():
-        return [e for e in jit_trace_log if e[0].startswith("prefill")]
+        return sorted(e for e in jit_trace_log if e[0].startswith("prefill"))
 
-    assert sorted(prefills()) == [("prefill_chunk", (1, CHUNK)),
-                                  ("prefill_chunk", (4, CHUNK))], prefills()
+    assert prefills() == [("prefill_chunk", (1, CHUNK)),
+                          ("prefill_chunk", (2, CHUNK)),
+                          ("prefill_chunk", (4, CHUNK))], prefills()
 
-    # warming a NON-boundary-length system prompt reuses the [1, chunk] shape
+    # warming a NON-boundary-length system prompt runs batch-1 [1, chunk]
     sys_prompt = rng.integers(3, cfg.vocab, 2 * CHUNK + 3).astype(np.int32)
     assert eng.warm_prefix(sys_prompt) == len(sys_prompt)
+    warmed = prefills()
+    assert warmed == [("prefill_chunk", (1, CHUNK)),
+                      ("prefill_chunk", (1, CHUNK)),
+                      ("prefill_chunk", (2, CHUNK)),
+                      ("prefill_chunk", (4, CHUNK))], warmed
     # serving more residues — including prefix-cache resumes — re-traces
-    # NOTHING: chunked admission is a two-shape program
+    # NOTHING
     more = [Request(np.concatenate([sys_prompt,
                                     rng.integers(3, cfg.vocab, 5 + i).astype(np.int32)]),
                     3, id=100 + i) for i in range(4)]
     res = eng.serve(more, slots=4)
     assert all(len(res[100 + i]) == 3 for i in range(4))
-    assert len(prefills()) == 2, prefills()
+    assert prefills() == warmed, prefills()
 
 
 def test_warm_prefix_nonboundary_registers_exact_length():

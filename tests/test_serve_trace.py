@@ -52,14 +52,15 @@ def test_tick_stats_count_what_the_engine_dispatched(model, generated, mode):
     eng = ServeEngine(params, cfg, max_len=128,
                       **MODES[mode].get("engine", {}))
     reqs = _requests(cfg)
-    # every prefill dispatch goes through _prefill_chunk or _prefill: record
-    # the [rows, width] of each
+    # every prefill dispatch goes through _prefill_chunk, _prefill or
+    # _ops_prefill_rows: record the [rows, width] of each
     shapes = []
-    for name in ("_prefill_chunk", "_prefill"):
+    for name in ("_prefill_chunk", "_prefill", "_ops_prefill_rows"):
         fn = getattr(eng, name)
 
         def probe(*args, _fn=fn, **kw):
-            shapes.append(tuple(kw["inputs"].shape))
+            toks = kw["inputs"] if "inputs" in kw else args[1]
+            shapes.append(tuple(toks.shape))
             return _fn(*args, **kw)
         setattr(eng, name, probe)
     ticks = []
@@ -78,6 +79,8 @@ def test_tick_stats_count_what_the_engine_dispatched(model, generated, mode):
     assert ts["prefill_dispatches"] == len(shapes)
     assert ts["prefill_valid_tokens"] == sum(lens)
     assert ts["prefill_slot_tokens"] == sum(r * w for r, w in shapes)
+    assert ts["prefill_rows_by_bucket"] == {
+        r: sum(s[0] == r for s in shapes) for r, _ in shapes}
     if mode in ("legacy", "monolithic"):        # dispatched unpadded
         assert ts["prefill_slot_tokens"] == sum(lens)
     else:
@@ -161,11 +164,51 @@ def test_prefill_phase_counts_padding():
     assert st["prefill_slot_tokens"] == 4 * 16 + 16
 
 
+def test_prefill_rows_by_bucket_counts_one_dispatch_a_tick(model):
+    """Staged arrivals of 1, 2, 3 and 5 one-chunk prompts into 8 slots:
+    each arrival tick runs one prefill dispatch, at the row bucket 1, 2, 4
+    and 8 that holds its admissions, and the slot tokens are the buckets'
+    rows times the chunk."""
+    cfg, params = model
+    eng = ServeEngine(params, cfg, max_len=128, prefill_chunk=CHUNK)
+    rng = np.random.default_rng(5)
+    arrivals = [0] + [3] * 2 + [6] * 3 + [9] * 5
+    reqs = [Request(rng.integers(3, cfg.vocab, int(rng.integers(5, CHUNK + 1)))
+                    .astype(np.int32), 2, id=i) for i in range(len(arrivals))]
+    per_tick = []       # dispatches by rows, one dict a tick
+    serve_tick = eng._serve_tick
+
+    def count_tick(run):
+        before = dict(eng.tick_stats["prefill_rows_by_bucket"])
+        serve_tick(run)
+        after = eng.tick_stats["prefill_rows_by_bucket"]
+        per_tick.append({r: n - before.get(r, 0) for r, n in after.items()
+                         if n != before.get(r, 0)})
+    eng._serve_tick = count_tick
+
+    eng.serve(reqs, slots=8, arrivals=arrivals)
+    assert [d for d in per_tick if d] == [{1: 1}, {2: 1}, {4: 1}, {8: 1}], \
+        per_tick
+    ts = eng.tick_stats
+    assert ts["prefill_rows_by_bucket"] == {1: 1, 2: 1, 4: 1, 8: 1}
+    assert ts["prefill_dispatches"] == 4
+    assert ts["prefill_slot_tokens"] == (1 + 2 + 4 + 8) * CHUNK
+    assert ts["prefill_valid_tokens"] == sum(len(r.prompt) for r in reqs)
+    assert "by rows {1: 1, 2: 1, 4: 1, 8: 1}" in trace_lib.summary(ts)
+    # one program per bucket, compiled ahead of the first dispatch, named
+    # so that a trace reader finding prefill programs by name finds it
+    (progs,) = eng._rows_programs.values()
+    assert sorted(progs) == [1, 2, 4, 8]
+    for p in progs.values():
+        assert "jit_prefill_chunk_rows" in p.as_text().split("\n", 1)[0]
+
+
 def test_engine_names_its_programs(model):
     """Profiler traces show jit_<function>, not jit__unknown."""
     cfg, params = model
     eng = ServeEngine(params, cfg, max_len=64)
     names = {"_prefill": "prefill", "_prefill_chunk": "prefill_chunk",
+             "_prefill_rows": "prefill_chunk_rows",
              "_step": "decode_step", "_verify": "spec_verify",
              "_insert": "insert_slot", "_extract": "extract_slot",
              "_reset": "reset_slot", "_sample": "sample_slot_tokens",

@@ -160,3 +160,20 @@ def test_stlt_base_decode_step_compiles(one_chip, compiled_kernel):
     fn = functools.partial(T.decode_step, cfg=cfg)
     text = _compile(fn, params, token_t=tok, state=state, node_cap=caps)
     assert "tpu_custom_call" not in text
+
+
+def test_stlt_base_prefill_chunk_rows_compiles(one_chip, compiled_kernel):
+    """The row-bucketed admission step: 2 of 4 pool rows gathered, a
+    [2, 256] masked chunk, the rows scattered back into the donated pool
+    in place (the pool's buffers alias the output's)."""
+    from repro.models import transformer as T
+
+    cfg, params, pool = _stlt_base(one_chip, batch=4, max_len=2048)
+    toks = _spec(one_chip, (2, 256), jnp.int32)
+    rows = _spec(one_chip, (2,), jnp.int32)
+    fn = functools.partial(T.prefill_chunk_rows, cfg=cfg)
+    text = jax.jit(fn, donate_argnames="pool").lower(
+        params, inputs=toks, pool=pool, idx=rows,
+        valid_len=rows).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "input_output_alias" in text
